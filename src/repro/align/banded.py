@@ -50,12 +50,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..core.pairwise import NEG, AlignResult
+from ..core.pairwise import NEG, M_ST, IX_ST, IY_ST, AlignResult
 # The pure band recurrence lives in kernels.banded.ref so the native
 # Pallas kernels and this jnp scan call the *same* math (bit-identical
 # parity by construction); re-exported here as the historical home.
 from ..kernels.banded.ref import (BandedForward, band_lo, band_row_init,
-                                  band_row_update, edge_pressure,
+                                  band_row_update, edge_pressure, end_state,
                                   trace_step_math)
 
 __all__ = ["BandedForward", "band_lo", "band_row_init", "band_row_update",
@@ -70,7 +70,7 @@ def banded_forward(a, la, b, lb, sub, gap_open, gap_extend, *, band: int):
     Returns a BandedForward whose dirs buffer is (n, band) — never the
     full (n+1)×(m+1) matrix.
     """
-    n = a.shape[0]
+    m = b.shape[0]
     W = band
     go = jnp.float32(gap_open)
     ge = jnp.float32(gap_extend)
@@ -78,8 +78,11 @@ def banded_forward(a, la, b, lb, sub, gap_open, gap_extend, *, band: int):
     la = la.astype(jnp.int32)
     lb = lb.astype(jnp.int32)
     mid = W // 2
+    offs = jnp.arange(W, dtype=jnp.int32)[None]
 
-    m0, ix0, iy0, cap0, hb0 = band_row_init(la, lb, go, ge, band=W)
+    m0, ix0, iy0, hb0 = band_row_init(la, lb, go, ge, band=W)
+    # end-cell capture init covers la == 0 (j = lb sits at offset W//2)
+    cap0 = jnp.stack([m0[0, mid], ix0[0, mid], iy0[0, mid]])
     lo0 = band_lo(jnp.int32(0), la, lb, W)
     margin = jnp.max(sub)                  # one diagonal step of headroom
 
@@ -87,28 +90,32 @@ def banded_forward(a, la, b, lb, sub, gap_open, gap_extend, *, band: int):
         m_prev, ix_prev, iy_prev, lo_prev, cap, edge, hb_prev = carry
         a_i, i = inp                       # i: 1-based DP row
         lo_i = band_lo(i, la, lb, W)
+        s_row = sub[a_i.astype(jnp.int32),
+                    b[jnp.clip(lo_i + offs - 1, 0, m - 1)].astype(jnp.int32)]
         m_new, ix_new, iy_new, dirs, h_new, h_prev, s = band_row_update(
-            m_prev, ix_prev, iy_prev, a_i, b, lo_prev, lo_i, sub, go, ge, lb)
+            m_prev, ix_prev, iy_prev, s_row, lo_prev, lo_i, go, ge, lb,
+            band=W)
 
         hit = i == la                      # end cell (la, lb) sits at mid
-        cap = jnp.where(hit, jnp.stack([m_new[mid], ix_new[mid],
-                                        iy_new[mid]]), cap)
+        cap = jnp.where(hit, jnp.stack([m_new[0, mid], ix_new[0, mid],
+                                        iy_new[0, mid]]), cap)
 
         # Edge pressure: a competitive cell in an exit zone means a
         # near-dominant path is fighting the band — a wider band could
         # beat this alignment, so flag the pair for full-DP fallback.
         live = i <= la
-        comp, hb = edge_pressure(h_new, h_prev, hb_prev, s, margin)
-        edge = edge | (live & comp)
+        comp, hb = edge_pressure(h_new, h_prev, hb_prev, s, margin, band=W)
+        edge = edge | (live & comp[0, 0])
         hb_prev = jnp.where(live, hb, hb_prev)
-        return (m_new, ix_new, iy_new, lo_i, cap, edge, hb_prev), dirs
+        return ((m_new, ix_new, iy_new, lo_i, cap, edge, hb_prev),
+                dirs[0].astype(jnp.int8))
 
-    rows_i = jnp.arange(1, n + 1, dtype=jnp.int32)
+    rows_i = jnp.arange(1, a.shape[0] + 1, dtype=jnp.int32)
     (_, _, _, _, cap, edge, _), dirs = jax.lax.scan(
         row_step, (m0, ix0, iy0, lo0, cap0, jnp.bool_(False), hb0),
         (a, rows_i))
-    st = jnp.argmax(cap).astype(jnp.int32)
-    return BandedForward(dirs, cap[st], la, lb, st, edge)
+    score, st = end_state(cap[0], cap[1], cap[2])
+    return BandedForward(dirs, score, la, lb, st.astype(jnp.int32), edge)
 
 
 def banded_traceback(a, b, fwd: BandedForward, gap_code: int, *, band: int):
@@ -130,10 +137,13 @@ def banded_traceback(a, b, fwd: BandedForward, gap_code: int, *, band: int):
         o = j - lo_i
         byte_band = dirf[jnp.clip((i - 1) * W + o, 0, n * W - 1)].astype(
             jnp.int32)
-        a_im1 = a[jnp.maximum(i - 1, 0)]
-        b_jm1 = b[jnp.maximum(j - 1, 0)]
-        ni, nj, nst, done, ndone, lost, edge_hit, ca, cb = trace_step_math(
-            i, j, o, st, done, byte_band, a_im1, b_jm1, lb, gap_code, W)
+        ni, nj, nst, done, ndone, lost, edge_hit = trace_step_math(
+            i, j, o, st, done, byte_band, lb, W)
+        is_m = st == M_ST
+        ca = jnp.where(is_m | (st == IX_ST), a[jnp.maximum(i - 1, 0)],
+                       gap_code).astype(jnp.int8)
+        cb = jnp.where(is_m | (st == IY_ST), b[jnp.maximum(j - 1, 0)],
+                       gap_code).astype(jnp.int8)
         oob = oob | lost
         edge = edge | edge_hit
         out_a = out_a.at[k].set(jnp.where(done, out_a[k], ca))

@@ -88,6 +88,13 @@ class PairsResult(NamedTuple):
                             # the coalescing metric repro.serve reports
 
 
+# A full-DP call (jnp / pallas) holds an O(n·m) int8 direction matrix per
+# pair on the device — about 275 MB per pair at mtDNA length. Host
+# dispatch splits every full-DP batch so one call holds at most this many
+# direction bytes.
+DIRS_BUDGET_BYTES = 2 << 30
+
+
 def _pad_cols(x, width: int, fill):
     if x.shape[-1] >= width:
         return x
@@ -206,10 +213,13 @@ class AlignEngine:
         plan = bucketing.bucket_plan(lens_np, Lmax,
                                      min_bucket=self.min_bucket)
         padded_cells = sum(width * len(idx) for width, idx in plan) * m
-        _record_dispatch("to_center", self.backend, len(plan), B,
+        calls = [(width, chunk) for width, idx in plan
+                 for chunk in self._chunks(idx, width, m,
+                                             full_dp=not self._is_banded)]
+        _record_dispatch("to_center", self.backend, len(calls), B,
                          real_cells, padded_cells)
-        if len(plan) == 1:
-            width, _ = plan[0]
+        if len(calls) == 1:
+            width, _ = calls[0]
             out = fn(Q[:, :width], lens, b, lb)
             return self._apply_fallback(out, Q, lens, b, lb, P)
 
@@ -218,7 +228,7 @@ class AlignEngine:
         b_rows = jnp.full((B, P), self.gap_code, jnp.int8)
         aln_len = jnp.zeros((B,), jnp.int32)
         ok = np.ones((B,), bool)
-        for width, idx in plan:
+        for width, idx in calls:
             ix = jnp.asarray(idx)
             out = fn(Q[ix, :width], lens[ix], b, lb)
             score = score.at[ix].set(out.score)
@@ -230,6 +240,22 @@ class AlignEngine:
                                          jnp.asarray(ok))
         return self._apply_fallback(merged, Q, lens, b, lb, P)
 
+    @staticmethod
+    def _chunks(idx: np.ndarray, n: int, m: int, *, full_dp: bool) -> list:
+        """Split one bucket's pair indices so no full-DP call holds more
+        than ``DIRS_BUDGET_BYTES`` of (n, m+1) direction matrices. Every
+        chunk has the same size (the last repeats its final index, whose
+        duplicate writes are identical) so one compiled shape serves all.
+        Banded calls hold O(n·W) and are never split."""
+        per = max(1, DIRS_BUDGET_BYTES // max(n * (m + 1), 1))
+        if not full_dp or len(idx) <= per:
+            return [idx]
+        chunks = [idx[i:i + per] for i in range(0, len(idx), per)]
+        last = chunks[-1]
+        chunks[-1] = np.concatenate(
+            [last, np.full(per - len(last), last[-1], last.dtype)])
+        return chunks
+
     def _apply_fallback(self, out: backends.BatchAlignment, Q, lens, b, lb,
                         P: int) -> EngineResult:
         """Re-align pairs the backend flagged (band overflow) with full DP."""
@@ -240,13 +266,17 @@ class AlignEngine:
         aln_len = out.aln_len
         if len(bad):
             _M_FALLBACK.labels(backend=self.backend).inc(len(bad))
-            _M_CALLS.labels(api="to_center", backend=self.backend).inc()
-            ix = jnp.asarray(bad)
-            res = self._full_dp_fn()(Q[ix], lens[ix], b, lb)
-            score = score.at[ix].set(res.score)
-            a_rows = a_rows.at[ix].set(_pad_cols(res.a_row, P, self.gap_code))
-            b_rows = b_rows.at[ix].set(_pad_cols(res.b_row, P, self.gap_code))
-            aln_len = aln_len.at[ix].set(res.aln_len)
+            for chunk in self._chunks(bad, Q.shape[1], b.shape[0],
+                                     full_dp=True):
+                _M_CALLS.labels(api="to_center", backend=self.backend).inc()
+                ix = jnp.asarray(chunk)
+                res = self._full_dp_fn()(Q[ix], lens[ix], b, lb)
+                score = score.at[ix].set(res.score)
+                a_rows = a_rows.at[ix].set(
+                    _pad_cols(res.a_row, P, self.gap_code))
+                b_rows = b_rows.at[ix].set(
+                    _pad_cols(res.b_row, P, self.gap_code))
+                aln_len = aln_len.at[ix].set(res.aln_len)
         return EngineResult(score, a_rows, b_rows, aln_len, len(bad))
 
     def pairs_fn(self, *, local: Optional[bool] = None,

@@ -18,6 +18,10 @@ Direction byte = dirM | dirIx << 2 | dirIy << 3, where
   dirIx in {0,1}: 0 = opened from M above, 1 = extended Ix above
   dirIy in {0,1}: 0 = opened from M left,  1 = extended Iy left
 
+Row 0 of the direction matrix is closed-form (FRESH, Iy opened at j = 1 and
+extended after), so it is never stored: ``ForwardResult.dirs`` holds DP
+rows 1..n, and ``traceback`` computes row-0 bytes itself.
+
 All scores are integer-valued float32 (exact up to 2^24), NEG = -1e7.
 """
 from __future__ import annotations
@@ -42,7 +46,8 @@ class AlignResult(NamedTuple):
 
 
 class ForwardResult(NamedTuple):
-    dirs: jnp.ndarray       # (La+1, Lb+1) int8 packed direction bytes
+    dirs: jnp.ndarray       # (>=La, >=Lb+1) int8 packed bytes, DP rows 1..
+                            # (row 0 is closed-form; padding is never read)
     score: jnp.ndarray      # f32
     start_i: jnp.ndarray
     start_j: jnp.ndarray
@@ -69,8 +74,6 @@ def gotoh_forward(a, la, b, lb, sub, gap_open, gap_extend, *, local=False):
     m0 = jnp.full((m + 1,), NEG).at[0].set(0.0)
     ix0 = jnp.full((m + 1,), NEG)
     iy0 = jnp.where(jnp.arange(m + 1) >= 1, -(go + (jcol - 1.0) * ge), NEG)
-    dir_iy0 = jnp.where(jnp.arange(m + 1) == 1, 0, 1)
-    dirs0 = _pack(jnp.full((m + 1,), FRESH, jnp.int32), jnp.zeros((m + 1,), jnp.int32), dir_iy0)
 
     def row_step(carry, a_i):
         m_prev, ix_prev, iy_prev, at_la_m, at_la_ix, at_la_iy, best, i = carry
@@ -130,8 +133,7 @@ def gotoh_forward(a, la, b, lb, sub, gap_open, gap_extend, *, local=False):
 
     best0 = (jnp.float32(NEG), jnp.int32(0), jnp.int32(0))
     init = (m0, ix0, iy0, m0, ix0, iy0, best0, jnp.int32(0))
-    (_, _, _, fm, fx, fy, best, _), dir_rows = jax.lax.scan(row_step, init, a)
-    dirs = jnp.concatenate([dirs0[None], dir_rows], axis=0)
+    (_, _, _, fm, fx, fy, best, _), dirs = jax.lax.scan(row_step, init, a)
 
     if local:
         score, bi, bj = best
@@ -143,14 +145,20 @@ def gotoh_forward(a, la, b, lb, sub, gap_open, gap_extend, *, local=False):
 
 
 def traceback(a, b, fwd: ForwardResult, gap_code: int):
-    """Walk packed directions back to an aligned pair (gap-padded rows)."""
+    """Walk packed directions back to an aligned pair (gap-padded rows).
+
+    ``fwd.dirs`` rows are DP rows 1..; it is indexed in place (2-D, no
+    reshape), so a padded kernel buffer is read without a relayout copy.
+    """
     n, m = a.shape[0], b.shape[0]
     out_len = n + m
-    dirf = fwd.dirs.reshape(-1)
 
     def step(t, carry):
         i, j, st, done, out_a, out_b, k = carry
-        byte = dirf[i * (m + 1) + j].astype(jnp.int32)
+        byte = FRESH | (jnp.where(j == 1, 0, 1) << 3)        # row 0
+        if fwd.dirs.shape[0]:
+            byte = jnp.where(i == 0, byte, fwd.dirs[jnp.maximum(i - 1, 0),
+                                                    j].astype(jnp.int32))
         dir_m = byte & 3
         dir_ix = (byte >> 2) & 1
         dir_iy = (byte >> 3) & 1

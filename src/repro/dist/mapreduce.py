@@ -173,48 +173,60 @@ def distributed_center_star(mesh: Mesh, *, method: str, sub, gap_code: int,
     return jax.jit(fn)
 
 
+def _count_fn(*, gap_code: int, n_chars: int, use_kernel: bool):
+    """(a, b) -> (match, valid) exact f32 counts: the Pallas distance kernel
+    or the jnp one-hot matmuls (``core.distance.match_valid_counts``)."""
+    if use_kernel:
+        from ..kernels.distance import match_valid_pallas
+        return functools.partial(match_valid_pallas, gap_code=gap_code,
+                                 n_chars=n_chars)
+    from ..core import distance as dist_mod
+    return functools.partial(dist_mod.match_valid_counts, gap_code=gap_code,
+                             n_chars=n_chars)
+
+
 def distance_strip_over_mesh(mesh: Mesh, *, gap_code: int, n_chars: int,
-                             correct: bool = True, data_axis: str = "data"):
-    """Tree-stage hook: jitted ``fn(rows_blk, S) -> (rb, N)`` distance strip.
+                             use_kernel: bool = False,
+                             data_axis: str = "data"):
+    """Tree-stage hook: jitted ``fn(rows_blk, S) -> (match, valid)``, each
+    an (rb, N) count strip.
 
     The phylogeny analogue of the MSA map stage: ``S`` is the full aligned
     row set sharded over ``data_axis`` (place once with
     ``sharding.shard_rows``; pad with ``pad_rows`` first), ``rows_blk`` a
-    replicated (row_block, L) block. Each device computes
-    ``cross_distance(rows_blk, its shard)`` — a row-block x column-block
-    tile — and the strip comes back concatenated over the column dim
-    (out spec ``P(None, data_axis)``). ``repro.phylo.tiles.TileContext``
+    replicated (row_block, L) block. Each device counts ``rows_blk``
+    against its shard — a row-block x column-block tile, through the
+    distance kernel when ``use_kernel`` — and the strip comes back
+    concatenated over the column dim (out spec ``P(None, data_axis)``).
+    The counts are exact, so ``repro.phylo.tiles.TileContext`` turns them
+    into distances exactly as its host tiles do (bit-identical strips); it
     streams these strips so no host holds more than one.
     """
-    from ..core import distance as dist_mod
-
-    def _strip(blk, S):
-        return dist_mod.cross_distance(blk, S, gap_code=gap_code,
-                                       n_chars=n_chars, correct=correct)
-
-    fn = sh.shard_map(_strip, mesh, in_specs=(P(), P(data_axis, None)),
-                      out_specs=P(None, data_axis), check_vma=False)
+    count = _count_fn(gap_code=gap_code, n_chars=n_chars,
+                      use_kernel=use_kernel)
+    fn = sh.shard_map(count, mesh, in_specs=(P(), P(data_axis, None)),
+                      out_specs=(P(None, data_axis), P(None, data_axis)),
+                      check_vma=False)
     return jax.jit(fn)
 
 
 def nearest_anchor_over_mesh(mesh: Mesh, *, gap_code: int, n_chars: int,
-                             correct: bool = True, data_axis: str = "data"):
-    """Tree-stage hook: jitted ``fn(S, anchors) -> (N, k)`` distances.
+                             use_kernel: bool = False,
+                             data_axis: str = "data"):
+    """Tree-stage hook: jitted ``fn(S, anchors) -> (match, valid)``, each
+    (N, k) counts.
 
     The assignment stage of the tiled HPTree pipeline: ``S`` is the full
     row set sharded over ``data_axis``, ``anchors`` the k medoid rows
-    replicated — each device computes its rows' distances to every medoid
-    (the transpose of ``distance_strip_over_mesh``'s tiling, chosen
-    because k << N so sharding the long axis is the one that balances).
+    replicated — each device counts its rows against every medoid (the
+    transpose of ``distance_strip_over_mesh``'s tiling, chosen because
+    k << N so sharding the long axis is the one that balances).
     """
-    from ..core import distance as dist_mod
-
-    def _nearest(S, A):
-        return dist_mod.cross_distance(S, A, gap_code=gap_code,
-                                       n_chars=n_chars, correct=correct)
-
-    fn = sh.shard_map(_nearest, mesh, in_specs=(P(data_axis, None), P()),
-                      out_specs=P(data_axis, None), check_vma=False)
+    count = _count_fn(gap_code=gap_code, n_chars=n_chars,
+                      use_kernel=use_kernel)
+    fn = sh.shard_map(count, mesh, in_specs=(P(data_axis, None), P()),
+                      out_specs=(P(data_axis, None), P(data_axis, None)),
+                      check_vma=False)
     return jax.jit(fn)
 
 
@@ -315,7 +327,7 @@ def center_row(center, lc, G, *, gap_code: int, out_len: int):
 
 
 def msa_over_mesh(seqs, cfg, mesh: Mesh, *, data_axis: str = "data",
-                  map_chunks: int = 1, out_pad: int = 64):
+                  map_chunks: Optional[int] = None, out_pad: int = 64):
     """Host driver: ``core.msa.center_star_msa`` semantics over a mesh.
 
     Handles everything the jitted pipeline cannot: center selection,
@@ -324,9 +336,14 @@ def msa_over_mesh(seqs, cfg, mesh: Mesh, *, data_axis: str = "data",
     trimming to the realized width. ``cfg`` is a ``core.msa.MSAConfig``.
     Returns a ``core.msa.MSAResult`` (``n_fallback=-1``: per-pair fallback
     counts are not tracked across shards).
+
+    ``map_chunks=None`` sizes the per-shard chunk loop so one chunk's
+    full-DP direction matrices stay within
+    ``align.engine.DIRS_BUDGET_BYTES``, as the host driver's calls do.
     """
     import numpy as np
 
+    from ..align.engine import DIRS_BUDGET_BYTES
     from ..core import kmer_index
 
     alpha = cfg.alpha()
@@ -340,6 +357,11 @@ def msa_over_mesh(seqs, cfg, mesh: Mesh, *, data_axis: str = "data",
     center, lc = S[cidx], lens[cidx]
     others = np.array([i for i in range(N) if i != cidx])
     n_shards = sh.axis_size(mesh, data_axis)
+    if map_chunks is None:
+        full_dp = cfg.backend not in ("banded", "banded-pallas")
+        per = max(1, DIRS_BUDGET_BYTES // (Lmax * (Lmax + 1)))
+        rows = -(-len(others) // n_shards)
+        map_chunks = -(-rows // per) if full_dp else 1
     # per-shard row count must also divide map_chunks for _chunked's reshape
     Q, n_q = pad_rows(np.asarray(S)[others], n_shards * map_chunks)
     qlens, _ = pad_rows(np.asarray(lens)[others], n_shards * map_chunks)

@@ -5,6 +5,12 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 
+LANES = 128          # TPU vector lanes: the last block dim is padded to this
+
+
+def round_up(x: int, k: int) -> int:
+    return -(-x // k) * k
+
 
 def default_interpret(platform: str | None = None) -> bool:
     """Platform-aware default for ``pallas_call(interpret=...)``.

@@ -1,32 +1,35 @@
 """Pallas TPU kernels: banded Gotoh forward + fused score-and-traceback.
 
-Two kernels over the same width-W band recurrence (``ref.band_row_update``
+Both kernels run the same width-W band recurrence (``ref.band_row_update``
 — the function the jnp scan in ``align.banded`` also calls, which is what
-makes parity bit-identical rather than approximate):
+makes parity bit-identical rather than approximate) from one kernel body:
 
-``_fwd_kernel`` — batch path. grid = (batch, row_blocks); the three band
-state vectors (M/Ix/Iy, each (W,) f32) live in VMEM scratch persisting
-across the sequential row-block dimension, rows advance as an
-anti-diagonal wavefront (all W band cells of a row are elementwise or
-cummax work on the VPU lanes), and HBM traffic per DP row is one (W,)
-int8 direction slab — O(n·W) instead of the SW kernel's O(n·m). The
-edge-pressure overflow detector runs in-kernel on the same row state, so
-the ``AlignEngine`` fallback contract needs no extra pass.
+forward — batch path. grid = (batch, row_blocks); the three band state
+rows (M/Ix/Iy) live in VMEM scratch persisting across the sequential
+row-block dimension, rows advance as an anti-diagonal wavefront (all W band
+cells of a row are elementwise or cummax work on the VPU lanes), and HBM
+traffic per DP row is one int8 direction slab — O(n·W) instead of the SW
+kernel's O(n·m). The edge-pressure overflow detector runs in-kernel on the
+same row state, so the ``AlignEngine`` fallback contract needs no extra
+pass.
 
-``_fused_kernel`` — coalesced ``align_pairs`` path. grid = (batch,); one
-program owns a whole pair: the forward loop writes direction bytes into a
-(n, W) int8 VMEM scratch, then the traceback walks that scratch in the
-same program. The direction matrix never exists in HBM at all — per pair
-the kernel moves only the sequences in and (score row, two gapped rows)
-out, which is the strictly-fewer-HBM-bytes claim BENCH_kernels checks.
+fused — coalesced ``align_pairs`` path. Same grid, but the direction rows
+go to an (n, Wp) VMEM scratch that persists across the row blocks, and the
+last row block walks that scratch back from the end cell. The direction
+matrix never exists in HBM at all: per pair the kernel moves only the
+sequences in and the path out — one int8 state code per alignment column
+(M / Ix / Iy, i.e. which of a and b the column consumes); ``ops`` turns
+the codes into the two gapped rows with a prefix sum and a gather.
 
-TPU layout notes: W is a pow2 (band plans clamp to pow2; 128-lane tiles
-want W >= 128 for full lane use, smaller W still vectorizes via sublane
-packing); the band state is 3·W·4 B + (8,) stats, and the fused scratch
-adds n·W int8 — at n = 4096, W = 64 that is ~256 KiB, inside one core's
-VMEM. Under ``interpret=True`` (CPU CI) the same kernels run on the
-Pallas interpreter; scalar gathers and dynamic stores are exact there,
-just not fast — see docs/KERNELS.md for the caveats.
+Layout for the TPU target (what Mosaic accepts): band rows are (1, Wp)
+with Wp = round_up(W, 128) lanes (lanes past W are held at NEG); the
+per-pair lengths are scalar-prefetch operands and the query residues an
+SMEM row block; the substitution row of a band is an aligned dynamic
+window of a per-target profile ``prof[c, x] = sub[c, b[x - pad]]`` (read
+as Wp + 128 lanes at a 128-aligned offset, then lane-rolled into place),
+so no gather runs in the kernel; the traceback reads one direction byte as
+a dynamic sublane row plus a lane select, and records its state codes the
+same way.
 """
 from __future__ import annotations
 
@@ -37,244 +40,241 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import kernel_call
+from .. import LANES, kernel_call, round_up
 from ...core.pairwise import NEG
 from .ref import (band_lo, band_row_init, band_row_update, edge_pressure,
-                  trace_step_math)
+                  end_state, trace_step_math)
 
-# stat scratch slots (f32): end-cell capture per Gotoh state, overflow
-# flag, and the previous live row's best score for edge pressure.
-_CAP_M, _CAP_IX, _CAP_IY, _EDGE, _HB = 0, 1, 2, 3, 4
+# rows of the (8, Wp) f32 state scratch: band rows, then per-pair stats
+# broadcast across the lanes (end-cell capture per Gotoh state, overflow
+# flag, previous live row's best score for edge pressure)
+_M, _IX, _IY, _CAP_M, _CAP_IX, _CAP_IY, _EDGE, _HB = range(8)
 
 
-def _fwd_kernel(a_ref, b_ref, lens_ref, sub_ref, dirs_ref, out_ref,
-                mp, xp, yp, stat, *, band: int, block_rows: int,
-                gap_open: float, gap_extend: float):
+def _row(st, i):
+    return st[i:i + 1, :]
+
+
+def _val(st, i):
+    return st[i:i + 1, 0:1]
+
+
+def _put(st, i, v):
+    st[i:i + 1, :] = jnp.broadcast_to(v, (1, st.shape[1]))
+
+
+def _window(prof_ref, a_i, x0, Wp: int):
+    """Lanes [x0, x0 + Wp) of profile row ``a_i``: a 128-aligned dynamic
+    load of Wp + 128 lanes, rolled left by the misalignment."""
+    base = pl.multiple_of((x0 // LANES) * LANES, LANES)
+    win = prof_ref[0, pl.ds(a_i, 1), pl.ds(base, Wp + LANES)]
+    d = x0 - base
+    return pltpu.roll(win, (Wp + LANES - d) % (Wp + LANES), 1)[:, :Wp]
+
+
+def _scalar(v):
+    """(1, k) int32 vector -> scalar (its max)."""
+    return jnp.max(v)
+
+
+def _kernel(lens_ref, margin_ref, a_ref, prof_ref, *refs, band: int,
+            block_rows: int, gap_open: float, gap_extend: float, pad: int,
+            out_len: int, fused: bool):
+    if fused:
+        out_ref, codes_ref, st, drow, cbuf = refs
+    else:
+        dirs_ref, out_ref, st, drow = refs
     W = band
     mid = W // 2
+    p = pl.program_id(0)
     rb = pl.program_id(1)
     n_rb = pl.num_programs(1)
-    la = lens_ref[0, 0]
-    lb = lens_ref[0, 1]
-    b_row = b_ref[0, :]
-    sub = sub_ref[:]
+    la = lens_ref[p, 0]
+    lb = lens_ref[p, 1]
+    margin = margin_ref[0]
+    Wp = st.shape[1]
+    x_max = prof_ref.shape[2] - Wp - LANES
     go = jnp.float32(gap_open)
     ge = jnp.float32(gap_extend)
-    margin = jnp.max(sub)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
     @pl.when(rb == 0)
     def _init():
-        m0, ix0, iy0, cap0, hb0 = band_row_init(la, lb, go, ge, band=W)
-        mp[:] = m0
-        xp[:] = ix0
-        yp[:] = iy0
-        stat[_CAP_M] = cap0[0]
-        stat[_CAP_IX] = cap0[1]
-        stat[_CAP_IY] = cap0[2]
-        stat[_EDGE] = 0.0
-        stat[_HB] = hb0
-        stat[5:] = jnp.zeros((3,), jnp.float32)
+        m0, ix0, iy0, hb0 = band_row_init(la, lb, go, ge, band=W, width=Wp)
+        _put(st, _M, m0)
+        _put(st, _IX, ix0)
+        _put(st, _IY, iy0)
+        _put(st, _CAP_M, m0[:, mid:mid + 1])
+        _put(st, _CAP_IX, ix0[:, mid:mid + 1])
+        _put(st, _CAP_IY, iy0[:, mid:mid + 1])
+        _put(st, _EDGE, jnp.float32(0.0))
+        _put(st, _HB, hb0)
 
     def row(l, _):
         r = rb * block_rows + l + 1          # DP row index (1-based)
-        a_i = a_ref[0, l]
         lo_prev = band_lo(r - 1, la, lb, W)
         lo_i = band_lo(r, la, lb, W)
+        # rows whose band starts past the profile hold no matrix cell
+        x0 = jnp.clip(lo_i - 1 + pad, 0, x_max)
+        s_row = _window(prof_ref, a_ref[0, 0, 0, l], x0, Wp)
         m_new, ix_new, iy_new, dirs, h_new, h_prev, s = band_row_update(
-            mp[:], xp[:], yp[:], a_i, b_row, lo_prev, lo_i, sub, go, ge, lb)
-        dirs_ref[0, l, :] = dirs
+            _row(st, _M), _row(st, _IX), _row(st, _IY), s_row, lo_prev,
+            lo_i, go, ge, lb, band=W, roll=pltpu.roll)
+        drow[pl.ds(r - 1 if fused else l, 1), :] = dirs
         # State advances unconditionally (the jnp scan does the same);
         # rows past la only touch the dead padding tail.
-        mp[:] = m_new
-        xp[:] = ix_new
-        yp[:] = iy_new
+        _put(st, _M, m_new)
+        _put(st, _IX, ix_new)
+        _put(st, _IY, iy_new)
 
         hit = r == la                        # end cell (la, lb) sits at mid
-        stat[_CAP_M] = jnp.where(hit, m_new[mid], stat[_CAP_M])
-        stat[_CAP_IX] = jnp.where(hit, ix_new[mid], stat[_CAP_IX])
-        stat[_CAP_IY] = jnp.where(hit, iy_new[mid], stat[_CAP_IY])
+        for dst, v in ((_CAP_M, m_new), (_CAP_IX, ix_new), (_CAP_IY, iy_new)):
+            _put(st, dst, jnp.where(hit, v[:, mid:mid + 1], _val(st, dst)))
 
         live = r <= la
-        comp, hb = edge_pressure(h_new, h_prev, stat[_HB], s, margin)
-        stat[_EDGE] = jnp.where(live & comp, 1.0, stat[_EDGE])
-        stat[_HB] = jnp.where(live, hb, stat[_HB])
+        comp, hb = edge_pressure(h_new, h_prev, _val(st, _HB), s, margin,
+                                 band=W)
+        _put(st, _EDGE, jnp.where(live & comp, 1.0, _val(st, _EDGE)))
+        _put(st, _HB, jnp.where(live, hb, _val(st, _HB)))
         return 0
 
     jax.lax.fori_loop(0, block_rows, row, 0)
+    if not fused:
+        dirs_ref[0] = drow[...].astype(jnp.int8)
 
     @pl.when(rb == n_rb - 1)
     def _fin():
-        ends = jnp.stack([stat[_CAP_M], stat[_CAP_IX], stat[_CAP_IY]])
-        st = jnp.argmax(ends)
-        out_ref[0, 0] = ends[st]
-        out_ref[0, 1] = la.astype(jnp.float32)
-        out_ref[0, 2] = lb.astype(jnp.float32)
-        out_ref[0, 3] = st.astype(jnp.float32)
-        out_ref[0, 4] = stat[_EDGE]
-        out_ref[0, 5:] = jnp.zeros((3,), jnp.float32)
+        score, state = end_state(_val(st, _CAP_M), _val(st, _CAP_IX),
+                                 _val(st, _CAP_IY))
+        edge_fwd = _val(st, _EDGE)
+        la_f = jnp.full((1, 1), la, jnp.int32).astype(jnp.float32)
+        lb_f = jnp.full((1, 1), lb, jnp.int32).astype(jnp.float32)
+        head = jnp.where(slot == 0, score,
+               jnp.where(slot == 1, la_f,
+               jnp.where(slot == 2, lb_f,
+               jnp.where(slot == 3, state.astype(jnp.float32), 0.0))))
+        if not fused:
+            out_ref[0] = jnp.where(slot == 4, edge_fwd, head)
+            return
+
+        # ---- traceback: walk the VMEM band, never touching HBM dirs ----
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, Wp), 1)
+        n_rows = drow.shape[0]
+        cbuf[...] = jnp.zeros(cbuf.shape, jnp.int32)
+
+        def tb_step(t, carry):
+            i, j, stt, done, edge, oob, k = carry
+            o = j - band_lo(i, la, lb, W)
+            brow = drow[pl.ds(jnp.clip(i - 1, 0, n_rows - 1), 1), :]
+            byte_band = _scalar(jnp.where(lane == o, brow, 0))
+            ni, nj, nst, done, ndone, lost, edge_hit = trace_step_math(
+                i, j, o, stt, done, byte_band, lb, W)
+            # column k consumes a and/or b as state ``stt`` says
+            kr = k // LANES
+            cur = cbuf[pl.ds(kr, 1), :]
+            cbuf[pl.ds(kr, 1), :] = jnp.where(
+                (slot == k % LANES) & jnp.logical_not(done), stt, cur)
+            return (jnp.where(done, i, ni), jnp.where(done, j, nj),
+                    jnp.where(done, stt, nst), ndone, edge | edge_hit,
+                    oob | lost, jnp.where(done, k, k + 1))
+
+        st0 = _scalar(jnp.broadcast_to(state, (1, LANES)))
+        init = (la, lb, st0, (la == 0) & (lb == 0), jnp.bool_(False),
+                jnp.bool_(False), jnp.int32(0))
+        (_, _, _, _, edge, oob, k) = jax.lax.fori_loop(0, out_len, tb_step,
+                                                       init)
+        bad = (edge | oob)
+        ok = jnp.where(bad, 0.0, jnp.where((edge_fwd > 0.5) | (score <= NEG / 2),
+                                           0.0, 1.0))
+        k_f = jnp.full((1, 1), k, jnp.int32).astype(jnp.float32)
+        out_ref[0] = jnp.where(slot == 4, k_f,
+                     jnp.where(slot == 5, ok,
+                     jnp.where(slot == 6, edge_fwd, head)))
+        codes_ref[0] = cbuf[...].astype(jnp.int8)
 
 
-def banded_forward_kernel(a, b, lens, sub, *, gap_open: float,
-                          gap_extend: float, band: int,
+def _vmem_limit(nbytes: int) -> int:
+    return int(min(max(2 * nbytes, 16 << 20), 100 << 20))
+
+
+def _call(a, prof, lens, margin, *, band: int, block_rows: int,
+          gap_open: float, gap_extend: float, pad: int, out_len: int,
+          fused: bool, interpret):
+    B, n = a.shape
+    C, P = prof.shape[1], prof.shape[2]
+    Wp = round_up(band, LANES)
+    assert n % block_rows == 0, (n, block_rows)
+    kern = functools.partial(_kernel, band=band, block_rows=block_rows,
+                             gap_open=gap_open, gap_extend=gap_extend,
+                             pad=pad, out_len=out_len, fused=fused)
+    head = pl.BlockSpec((1, 1, LANES), lambda p, r, *_: (p, 0, 0))
+    out_head = jax.ShapeDtypeStruct((B, 1, LANES), jnp.float32)
+    scratch = [pltpu.VMEM((8, Wp), jnp.float32)]
+    if fused:
+        kr = -(-out_len // LANES)
+        out_specs = [head, pl.BlockSpec((1, kr, LANES),
+                                        lambda p, r, *_: (p, 0, 0))]
+        out_shape = [out_head,
+                     jax.ShapeDtypeStruct((B, kr, LANES), jnp.int8)]
+        scratch += [pltpu.VMEM((n, Wp), jnp.int32),
+                    pltpu.VMEM((kr, LANES), jnp.int32)]
+        vmem = n * Wp * 4 + kr * LANES * 5
+    else:
+        out_specs = [pl.BlockSpec((1, block_rows, Wp),
+                                  lambda p, r, *_: (p, r, 0)), head]
+        out_shape = [jax.ShapeDtypeStruct((B, n, Wp), jnp.int8), out_head]
+        scratch += [pltpu.VMEM((block_rows, Wp), jnp.int32)]
+        vmem = block_rows * Wp * 6
+    vmem += 2 * round_up(C, 8) * P * 4
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n // block_rows),
+        in_specs=[
+            pl.BlockSpec((1, 1, 1, block_rows),
+                         lambda p, r, *_: (p, r, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, C, P), lambda p, r, *_: (p, 0, 0)),
+        ],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    return kernel_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(vmem)),
+        interpret=interpret,
+    )(lens, margin, a.reshape(B, n // block_rows, 1, block_rows), prof)
+
+
+def banded_forward_kernel(a, prof, lens, margin, *, gap_open: float,
+                          gap_extend: float, band: int, pad: int,
                           block_rows: int = 128,
                           interpret: bool | None = None):
-    """a: (B, n) int8 (n % block_rows == 0), b: (B, m), lens: (B, 2) i32.
+    """a: (B, n) int32 (n % block_rows == 0), prof: (B, C, P) f32 profile
+    (column x = b[x - pad]), lens: (B, 2) i32, margin: (1,) f32.
 
-    Returns dirs (B, n, band) int8 (DP rows 1..n) and out (B, 8) f32
-    [score, la, lb, start_state, edge, 0*3].
+    Returns dirs (B, n, Wp) int8 (DP rows 1..n, lanes past ``band`` are
+    padding) and out (B, 1, 128) f32 [score, la, lb, start_state, edge, 0...].
     """
-    B, n = a.shape
-    m = b.shape[1]
-    assert n % block_rows == 0, (n, block_rows)
-    grid = (B, n // block_rows)
-    kern = functools.partial(_fwd_kernel, band=band, block_rows=block_rows,
-                             gap_open=gap_open, gap_extend=gap_extend)
-    return kernel_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_rows), lambda b_, r: (b_, r)),
-            pl.BlockSpec((1, m), lambda b_, r: (b_, 0)),
-            pl.BlockSpec((1, 2), lambda b_, r: (b_, 0)),
-            pl.BlockSpec(sub.shape, lambda b_, r: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_rows, band), lambda b_, r: (b_, r, 0)),
-            pl.BlockSpec((1, 8), lambda b_, r: (b_, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, n, band), jnp.int8),
-            jax.ShapeDtypeStruct((B, 8), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((band,), jnp.float32),
-            pltpu.VMEM((band,), jnp.float32),
-            pltpu.VMEM((band,), jnp.float32),
-            pltpu.VMEM((8,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(a, b, lens, sub)
+    return _call(a, prof, lens, margin, band=band, block_rows=block_rows,
+                 gap_open=gap_open, gap_extend=gap_extend, pad=pad,
+                 out_len=0, fused=False, interpret=interpret)
 
 
-def _fused_kernel(a_ref, b_ref, lens_ref, sub_ref, out_ref, ar_ref, br_ref,
-                  dirs_s, *, band: int, gap_open: float, gap_extend: float,
-                  gap_code: int):
-    W = band
-    mid = W // 2
-    n = a_ref.shape[1]
-    m = b_ref.shape[1]
-    out_len = n + m
-    la = lens_ref[0, 0]
-    lb = lens_ref[0, 1]
-    b_row = b_ref[0, :]
-    sub = sub_ref[:]
-    go = jnp.float32(gap_open)
-    ge = jnp.float32(gap_extend)
-    margin = jnp.max(sub)
-
-    # ---- forward: band state as loop carry, dirs into VMEM scratch ----
-    m0, ix0, iy0, cap0, hb0 = band_row_init(la, lb, go, ge, band=W)
-
-    def fwd_row(l, carry):
-        m_prev, ix_prev, iy_prev, cap, edge, hb_prev = carry
-        r = l + 1
-        a_i = a_ref[0, l]
-        lo_prev = band_lo(r - 1, la, lb, W)
-        lo_i = band_lo(r, la, lb, W)
-        m_new, ix_new, iy_new, dirs, h_new, h_prev, s = band_row_update(
-            m_prev, ix_prev, iy_prev, a_i, b_row, lo_prev, lo_i, sub,
-            go, ge, lb)
-        pl.store(dirs_s, (pl.dslice(l, 1), slice(None)), dirs[None, :])
-        hit = r == la
-        cap = jnp.where(hit, jnp.stack([m_new[mid], ix_new[mid],
-                                        iy_new[mid]]), cap)
-        live = r <= la
-        comp, hb = edge_pressure(h_new, h_prev, hb_prev, s, margin)
-        edge = edge | (live & comp)
-        hb_prev = jnp.where(live, hb, hb_prev)
-        return (m_new, ix_new, iy_new, cap, edge, hb_prev)
-
-    (_, _, _, cap, edge_fwd, _) = jax.lax.fori_loop(
-        0, n, fwd_row, (m0, ix0, iy0, cap0, jnp.bool_(False), hb0))
-    st0 = jnp.argmax(cap).astype(jnp.int32)
-    score = cap[st0]
-
-    # ---- traceback: walk the VMEM band, never touching HBM dirs ----
-    dirf = dirs_s[:].reshape(-1)
-
-    def tb_step(t, carry):
-        i, j, st, done, edge, oob, out_a, out_b, k = carry
-        lo_i = band_lo(i, la, lb, W)
-        o = j - lo_i
-        byte_band = dirf[jnp.clip((i - 1) * W + o, 0, n * W - 1)].astype(
-            jnp.int32)
-        a_im1 = a_ref[0, jnp.maximum(i - 1, 0)]
-        b_jm1 = b_ref[0, jnp.maximum(j - 1, 0)]
-        ni, nj, nst, done, ndone, lost, edge_hit, ca, cb = trace_step_math(
-            i, j, o, st, done, byte_band, a_im1, b_jm1, lb, gap_code, W)
-        oob = oob | lost
-        edge = edge | edge_hit
-        out_a = out_a.at[k].set(jnp.where(done, out_a[k], ca))
-        out_b = out_b.at[k].set(jnp.where(done, out_b[k], cb))
-        k = jnp.where(done, k, k + 1)
-        i = jnp.where(done, i, ni)
-        j = jnp.where(done, j, nj)
-        st = jnp.where(done, st, nst)
-        return (i, j, st, ndone, edge, oob, out_a, out_b, k)
-
-    out_a = jnp.full((out_len,), gap_code, jnp.int8)
-    out_b = jnp.full((out_len,), gap_code, jnp.int8)
-    init = (la, lb, st0, (la == 0) & (lb == 0),
-            jnp.bool_(False), jnp.bool_(False), out_a, out_b, jnp.int32(0))
-    (_, _, _, _, edge, oob, out_a, out_b, k) = jax.lax.fori_loop(
-        0, out_len, tb_step, init)
-
-    ok = (~edge) & (~oob) & (~edge_fwd) & (score > NEG / 2)
-    ar_ref[0, :] = jnp.roll(jnp.flip(out_a), k - out_len)
-    br_ref[0, :] = jnp.roll(jnp.flip(out_b), k - out_len)
-    out_ref[0, 0] = score
-    out_ref[0, 1] = la.astype(jnp.float32)
-    out_ref[0, 2] = lb.astype(jnp.float32)
-    out_ref[0, 3] = st0.astype(jnp.float32)
-    out_ref[0, 4] = k.astype(jnp.float32)
-    out_ref[0, 5] = ok.astype(jnp.float32)
-    out_ref[0, 6] = edge_fwd.astype(jnp.float32)
-    out_ref[0, 7] = 0.0
-
-
-def banded_fused_kernel(a, b, lens, sub, *, gap_open: float,
-                        gap_extend: float, band: int, gap_code: int = 5,
+def banded_fused_kernel(a, prof, lens, margin, *, gap_open: float,
+                        gap_extend: float, band: int, pad: int, out_len: int,
+                        block_rows: int = 128,
                         interpret: bool | None = None):
-    """Fused banded score+traceback. a: (B, n) int8, b: (B, m), lens (B, 2).
+    """Fused banded score+traceback; operands as ``banded_forward_kernel``.
 
-    Returns out (B, 8) f32 [score, la, lb, st, aln_len, ok, edge, 0] and
-    a_row/b_row (B, n+m) int8 — no direction matrix ever reaches HBM.
+    Returns out (B, 1, 128) f32 [score, la, lb, st, aln_len, ok, edge, 0...]
+    and codes (B, ceil(out_len/128), 128) int8: the state (M/Ix/Iy) of each
+    alignment column from the end cell back, valid for the first aln_len
+    entries — no direction matrix ever reaches HBM.
     """
-    B, n = a.shape
-    m = b.shape[1]
-    kern = functools.partial(_fused_kernel, band=band, gap_open=gap_open,
-                             gap_extend=gap_extend, gap_code=gap_code)
-    return kernel_call(
-        kern,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda b_: (b_, 0)),
-            pl.BlockSpec((1, m), lambda b_: (b_, 0)),
-            pl.BlockSpec((1, 2), lambda b_: (b_, 0)),
-            pl.BlockSpec(sub.shape, lambda b_: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 8), lambda b_: (b_, 0)),
-            pl.BlockSpec((1, n + m), lambda b_: (b_, 0)),
-            pl.BlockSpec((1, n + m), lambda b_: (b_, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 8), jnp.float32),
-            jax.ShapeDtypeStruct((B, n + m), jnp.int8),
-            jax.ShapeDtypeStruct((B, n + m), jnp.int8),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n, band), jnp.int8),
-        ],
-        interpret=interpret,
-    )(a, b, lens, sub)
+    return _call(a, prof, lens, margin, band=band, block_rows=block_rows,
+                 gap_open=gap_open, gap_extend=gap_extend, pad=pad,
+                 out_len=out_len, fused=True, interpret=interpret)

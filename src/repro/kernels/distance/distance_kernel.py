@@ -8,18 +8,19 @@ HBM would multiply sequence bytes by 4*|alphabet|. This kernel builds the
 one-hot tiles in VMEM from the int8 tiles at use time, so HBM traffic stays
 int8 while the MXU does the counting.
 
-Profile packing (``pack``): the default ``"int8"`` keeps the one-hot tiles
-as int8 operands of an int32-accumulating dot — 4× fewer VMEM bytes per
-expanded tile than the legacy ``"f32"`` path (BN*BL*C bytes instead of
-BN*BL*C*4; 128*128*8 = 128 KiB at C=8) and the layout the MXU's integer
-path wants. Counts are exact small integers either way, so the f32 results
-the ops layer returns are bit-identical between packings.
+Profile packing (``pack``): the default ``"int8"`` feeds the one-hot tiles
+as int8 operands of an int32-accumulating dot (the MXU's integer path); the
+legacy ``"f32"`` path feeds f32. Counts are exact small integers either
+way, so the f32 results the ops layer returns are bit-identical between
+packings.
 
 Tiling: grid (N/BN, N/BN, L/BL); A-tile (BN, BL) int8 and B-tile (BN, BL)
-int8 expand to (BN, BL*C) in VMEM and accumulate two (BN, BN) outputs over
-the L/BL reduction dimension (last grid dim = sequential on TPU,
-accumulation in the output block is the standard Pallas matmul pattern).
-MXU dims: BN=128 rows, BL*C a multiple of 128 lanes.
+int8. The match count is the sum over characters c of (A == c) @ (B == c)^T
+— C two-dimensional (BN, BL) x (BL, BN) dots, each one-hot tile a compare
+away from the int8 tile — accumulated with the valid count into two
+(BN, BN) outputs over the L/BL reduction dimension (last grid dim =
+sequential on TPU, accumulation in the output block is the standard Pallas
+matmul pattern). MXU dims: BN=128 rows, BL a multiple of 128 lanes.
 """
 from __future__ import annotations
 
@@ -43,21 +44,25 @@ def _kernel(a_ref, b_ref, match_ref, valid_ref, *, n_chars: int,
         match_ref[:, :] = jnp.zeros_like(match_ref)
         valid_ref[:, :] = jnp.zeros_like(valid_ref)
 
-    a = a_ref[:, :]
-    b = b_ref[:, :]
+    a = a_ref[:, :].astype(jnp.int32)
+    b = b_ref[:, :].astype(jnp.int32)
 
-    def onehot(x):
-        oh = (x[:, :, None] == jax.lax.broadcasted_iota(jnp.int8, (1, 1, n_chars), 2))
-        oh &= (x[:, :, None] != gap_code)
-        return oh.astype(op_t).reshape(x.shape[0], -1)
+    def nt_dot(x, y):                 # x @ y.T on the MXU
+        return jax.lax.dot_general(x.astype(op_t), y.astype(op_t),
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=acc_t)
 
-    na = ((a != gap_code) & (a < n_chars)).astype(op_t)
-    nb = ((b != gap_code) & (b < n_chars)).astype(op_t)
-    valid_ref[:, :] += jax.lax.dot_general(
-        na, nb, (((1,), (1,)), ((), ())), preferred_element_type=acc_t)
-    match_ref[:, :] += jax.lax.dot_general(
-        onehot(a), onehot(b), (((1,), (1,)), ((), ())),
-        preferred_element_type=acc_t)
+    valid_ref[:, :] += nt_dot((a != gap_code) & (a < n_chars),
+                              (b != gap_code) & (b < n_chars))
+    # one-hot match counts as a sum of per-character 2-D dots: the
+    # one-hot tile of character c is just (x == c), so no 3-D expansion
+    match = None
+    for c in range(n_chars):
+        if c == gap_code:
+            continue
+        d = nt_dot(a == c, b == c)
+        match = d if match is None else match + d
+    match_ref[:, :] += match
 
 
 def match_valid_kernel(msa_a, msa_b, *, n_chars: int, gap_code: int,

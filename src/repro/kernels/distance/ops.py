@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.distance import jc69_distance
+from .. import LANES, round_up
 from .distance_kernel import match_valid_kernel
 
 
@@ -20,6 +21,9 @@ def match_valid_pallas(msa_a, msa_b, *, n_chars: int, gap_code: int,
     exact integers either way, so both packings are bit-identical."""
     N, L = msa_a.shape
     M = msa_b.shape[0]
+    # whole (128, 128) tiles: the int8 operand tiles and the int32 count
+    # tiles are lane-dense on the TPU; padding rows/columns are gaps
+    bn, bl = round_up(bn, LANES), round_up(bl, LANES)
     pn, pm, pl_ = (-N) % bn, (-M) % bn, (-L) % bl
     a = jnp.pad(msa_a, ((0, pn), (0, pl_)), constant_values=gap_code)
     b = jnp.pad(msa_b, ((0, pm), (0, pl_)), constant_values=gap_code)

@@ -1,5 +1,6 @@
-"""jit'd public wrapper for the SW/Gotoh kernel: padding, boundary row,
-and a drop-in replacement for pairwise.gotoh_forward in batch form."""
+"""jit'd public wrapper for the SW/Gotoh kernel: padding, the per-target
+substitution profile, and a drop-in replacement for pairwise.gotoh_forward
+in batch form."""
 from __future__ import annotations
 
 import functools
@@ -8,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.pairwise import ForwardResult
-from . import ref as _ref
+from .. import LANES, round_up
 from .sw_kernel import gotoh_forward_kernel
 
 
@@ -17,25 +18,31 @@ from .sw_kernel import gotoh_forward_kernel
 def gotoh_forward_pallas(a, b, lens, sub, *, gap_open, gap_extend,
                          local=False, block_rows: int = 128,
                          interpret: bool | None = None) -> ForwardResult:
-    """Batched forward with the kernel; returns ForwardResult with the
-    boundary row prepended so core.pairwise.traceback consumes it directly.
+    """Batched forward with the kernel, as a batched ForwardResult.
 
     a: (B, n) int8, b: (B, m) int8, lens: (B, 2) i32 [[la, lb], ...].
-    ``interpret=None`` resolves platform-aware (compiled on TPU) inside
-    the shared ``kernels.kernel_call`` wrapper.
+    ``dirs`` holds DP rows 1..n (row 0 is closed-form) as a padded
+    (B, n_pad, Mp) int8 buffer, Mp = round_up(m+1, 128);
+    ``core.pairwise.traceback`` consumes it as is. ``interpret=None``
+    resolves platform-aware (compiled on TPU) inside the shared
+    ``kernels.kernel_call`` wrapper.
     """
     B, n = a.shape
     m = b.shape[1]
-    npad = (-n) % block_rows
-    a = jnp.pad(a, ((0, 0), (0, npad)))
-    dirs_body, out = gotoh_forward_kernel(
-        a, b, lens, sub.astype(jnp.float32), gap_open=float(gap_open),
-        gap_extend=float(gap_extend), local=local, block_rows=block_rows,
+    Mp = round_up(m + 1, LANES)
+    # row blocks: a multiple of 32 (the int8 tile height), or one block
+    # covering the whole (8-row padded) query
+    br = min(round_up(block_rows, 32), round_up(max(n, 1), 8))
+    a = jnp.pad(a.astype(jnp.int32), ((0, 0), (0, (-n) % br)))
+    sub = sub.astype(jnp.float32)
+    # prof[p, c, j] = sub[c, b[p, j-1]]; column 0 and the lane padding are 0
+    prof = jnp.transpose(sub[:, b.astype(jnp.int32)], (1, 0, 2))
+    prof = jnp.pad(prof, ((0, 0), (0, 0), (1, Mp - m - 1)))
+    dirs, out = gotoh_forward_kernel(
+        a, prof, lens.astype(jnp.int32), gap_open=float(gap_open),
+        gap_extend=float(gap_extend), local=local, block_rows=br,
         interpret=interpret)
-    dirs_body = dirs_body[:, :n, :]
-    row0 = _ref.boundary_row(m, lens[:, 1])
-    dirs = jnp.concatenate([jnp.broadcast_to(row0, (B, 1, m + 1)), dirs_body],
-                           axis=1)
+    out = out[:, 0, :]
     return ForwardResult(dirs, out[:, 0], out[:, 1].astype(jnp.int32),
                          out[:, 2].astype(jnp.int32),
                          out[:, 3].astype(jnp.int32))

@@ -18,14 +18,7 @@ def gotoh_forward_ref(a, b, lens, sub, *, gap_open: float, gap_extend: float,
                          fwd.start_j.astype(jnp.float32),
                          fwd.start_state.astype(jnp.float32),
                          0.0, 0.0, 0.0, 0.0])
-        return fwd.dirs[1:], out      # body rows only (kernel omits row 0)
+        return fwd.dirs, out          # DP rows 1..n (row 0 is closed-form)
 
     return jax.vmap(one)(a, b, lens)
 
-
-def boundary_row(m: int, lb, *, gap_code_unused=None):
-    """Packed direction row 0 (constant given lb): FRESH | open-from-M at j=1."""
-    from ...core.pairwise import FRESH
-    dir_iy0 = jnp.where(jnp.arange(m + 1) == 1, 0, 1)
-    row0 = (jnp.full((m + 1,), FRESH, jnp.int32) | (dir_iy0 << 3)).astype(jnp.int8)
-    return row0

@@ -95,6 +95,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     parser = build_parser()
     args = parser.parse_args(argv)
+    from . import compile_cache
+    compile_cache.enable()
     if args.tree == "ml" and args.alphabet == "protein":
         parser.error("--tree ml needs a nucleotide alphabet (the 4-state "
                      "likelihood); use --tree cluster/tiled for protein")

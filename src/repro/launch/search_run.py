@@ -114,6 +114,8 @@ def _safe_name(name: str) -> str:
 def main(argv=None):
     parser = build_parser()
     args = parser.parse_args(argv)
+    from . import compile_cache
+    compile_cache.enable()
     from ..obs import export as obs_export
     from ..obs import trace as _trace
     with _trace.request_trace(), _trace.span("search_run", query=args.query):

@@ -149,6 +149,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     parser = build_parser()
     args = parser.parse_args(argv)
+    from . import compile_cache
+    compile_cache.enable()
     if args.tree_bootstrap > 0 and args.tree_refine != "ml":
         parser.error("--tree-bootstrap requires --tree-refine ml "
                      "(otherwise every plain /tree request would 400)")

@@ -136,6 +136,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     parser = build_parser()
     args = parser.parse_args(argv)
+    from . import compile_cache
+    compile_cache.enable()
     if args.bootstrap > 0 and args.refine == "none":
         parser.error("--bootstrap requires --refine ml or search")
     if args.refine != "none" and args.alphabet == "protein":

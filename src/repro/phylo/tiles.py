@@ -121,12 +121,17 @@ class TileContext:
             from ..kernels.distance import match_valid_pallas
             m, v = match_valid_pallas(rows, cols, n_chars=self.n_chars,
                                       gap_code=self.gap_code)
-            d = dist_mod.counts_to_distance(m, v, correct=self.correct)
         else:
-            d = dist_mod.cross_distance(rows, cols, gap_code=self.gap_code,
-                                        n_chars=self.n_chars,
-                                        correct=self.correct)
-        return np.asarray(d)
+            m, v = dist_mod.match_valid_counts(rows, cols,
+                                               gap_code=self.gap_code,
+                                               n_chars=self.n_chars)
+        return self._distance(m, v)
+
+    def _distance(self, match, valid) -> np.ndarray:
+        """The shared JC69 tail of host tiles and shard-mapped count strips:
+        one program for both, so a mesh changes no distance bit."""
+        return np.asarray(dist_mod.counts_to_distance(match, valid,
+                                                      correct=self.correct))
 
     def square(self, rows, pad_to: Optional[int] = None) -> np.ndarray:
         """Small dense symmetric matrix (per-cluster / skeleton blocks).
@@ -173,7 +178,8 @@ class TileContext:
                               msa.dtype)
                 blk = np.concatenate([blk, pad], axis=0)
             if mesh_fn is not None:
-                strip = np.asarray(mesh_fn(jnp.asarray(blk), S))
+                match, valid = mesh_fn(jnp.asarray(blk), S)
+                strip = self._distance(match[:, :m], valid[:, :m])
             else:
                 strip = self.block(blk, cols_arr)
             strip = strip[: stop - start, :m]
@@ -190,7 +196,7 @@ class TileContext:
         S = sh.shard_rows(padded, self.mesh, self.data_axis)
         fn = mapreduce.distance_strip_over_mesh(
             self.mesh, gap_code=self.gap_code, n_chars=self.n_chars,
-            correct=self.correct, data_axis=self.data_axis)
+            use_kernel=self.use_kernel, data_axis=self.data_axis)
         return fn, S
 
     def row_sums(self, msa) -> np.ndarray:
@@ -239,10 +245,10 @@ class TileContext:
             padded, _ = mapreduce.pad_rows(msa, n_shards, fill=self.gap_code)
             fn = mapreduce.nearest_anchor_over_mesh(
                 self.mesh, gap_code=self.gap_code, n_chars=self.n_chars,
-                correct=self.correct, data_axis=self.data_axis)
-            xd = fn(sh.shard_rows(padded, self.mesh, self.data_axis),
-                    sh.broadcast(jnp.asarray(anchors), self.mesh))
-            return self.track(np.asarray(xd)[:n].copy())
+                use_kernel=self.use_kernel, data_axis=self.data_axis)
+            match, valid = fn(sh.shard_rows(padded, self.mesh, self.data_axis),
+                              sh.broadcast(jnp.asarray(anchors), self.mesh))
+            return self.track(self._distance(match[:n], valid[:n]).copy())
         out = self.track(np.empty((n, anchors.shape[0]), np.float32))
         for start, stop, strip in self.strips(msa, cols=anchors):
             out[start:stop] = strip

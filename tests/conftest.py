@@ -5,8 +5,16 @@ import sys
 # ONLY in launch/dryrun.py (run via subprocess in test_dryrun_small.py).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import numpy as np
-import pytest
+# The launchers turn JAX's persistent compilation cache on
+# (repro.launch.compile_cache); the test suite keeps it off, in this
+# process and in the launcher subprocesses some tests start.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture(scope="session")

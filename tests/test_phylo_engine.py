@@ -76,30 +76,44 @@ def test_strips_respect_budget():
     assert acct.peak == 16 * 50 * 4
 
 
-def test_mesh_strip_hook_parity():
-    """Shard-mapped strips (dist.mapreduce hook) == dense sub-blocks.
-
-    Counts are exact either way; shard_map compiles a different program, so
-    the JC69 log may differ in the last ulps — allclose, not array_equal.
-    """
+def _mesh_parity(use_kernel):
+    """Shard-mapped count strips and nearest-anchor counts (the
+    dist.mapreduce hooks) give the host tiles' distances bit for bit: the
+    mesh moves only exact counts, and the JC69 tail is the host's."""
     from repro.launch.mesh import make_local_mesh
     msa = _rand_msa(39, 64, seed=7)
     mesh = make_local_mesh((1, 1), ("data", "model"))
     out = np.zeros((39, 39), np.float32)
-    for start, stop, strip in _ctx(row_block=16, mesh=mesh).strips(msa):
+    host = np.zeros((39, 39), np.float32)
+    for start, stop, strip in _ctx(row_block=16, mesh=mesh,
+                                   use_kernel=use_kernel).strips(msa):
         out[start:stop] = strip
+    for start, stop, strip in _ctx(row_block=16,
+                                   use_kernel=use_kernel).strips(msa):
+        host[start:stop] = strip
+    np.testing.assert_array_equal(out, host)
     np.fill_diagonal(out, 0.0)
     np.testing.assert_allclose(out, _dense(msa), rtol=1e-5, atol=1e-6)
 
     # the assignment stage's shard-mapped path (rows sharded, anchors
-    # replicated) against the host cross-distance
-    ctx = _ctx(row_block=16, mesh=mesh)
+    # replicated) against the host strips
+    ctx = _ctx(row_block=16, mesh=mesh, use_kernel=use_kernel)
     xd = ctx.nearest(msa, msa[:5])
-    host = np.asarray(distance.cross_distance(
-        jnp.asarray(msa), jnp.asarray(msa[:5]), gap_code=GAP, n_chars=NCH))
-    np.testing.assert_allclose(xd, host, rtol=1e-5, atol=1e-6)
+    host_xd = _ctx(row_block=16, use_kernel=use_kernel).nearest(msa, msa[:5])
+    np.testing.assert_array_equal(xd, host_xd)
     ctx.release(xd)
     assert ctx.accountant.resident == 0
+
+
+def test_mesh_strip_hook_parity():
+    """Mesh hooks on the jnp counts == host tiles, bit for bit."""
+    _mesh_parity(use_kernel=False)
+
+
+def test_mesh_strip_hook_kernel_parity():
+    """Mesh hooks on the distance kernel (interpreted off the TPU) ==
+    host kernel tiles, bit for bit — the chip's --dist --tree tiled path."""
+    _mesh_parity(use_kernel=True)
 
 
 # ------------------------------------------------------------- pipeline

@@ -1,0 +1,80 @@
+"""Every Pallas kernel of the main path compiles for a TPU v5e chip at real
+widths, without a chip: the TPU compiler compiles for a described (not
+attached) v5e:2x2 topology, and refuses what interpret mode accepts —
+unaligned blocks, scalar VMEM stores, unsupported shape casts, gathers.
+
+All cases live in this one file so the one test worker that describes the
+topology (and so holds the TPU library) compiles all of them.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import alphabet as ab
+
+SUB = ab.dna_matrix().shape            # (6, 6) DNA substitution matrix
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:               # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text        # the Pallas kernel is in it
+
+
+@pytest.mark.parametrize("local", [False, True])
+def test_sw_forward_16s(one_chip, local):
+    """SW forward at the 16S cell: B=8, n = m = 1536."""
+    from repro.kernels.sw.ops import gotoh_forward_pallas
+
+    B, n = 8, 1536
+    _compile(lambda a, b, l, s: gotoh_forward_pallas(
+        a, b, l, s, gap_open=3, gap_extend=1, local=local, interpret=False),
+        one_chip, ((B, n), jnp.int8), ((B, n), jnp.int8),
+        ((B, 2), jnp.int32), (SUB, jnp.float32))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_banded_mtdna(one_chip, fused):
+    """Banded forward and fused pairs at mtDNA length padded to 128
+    (n = m = 16896), band 128."""
+    from repro.kernels.banded.ops import banded_forward_pallas, banded_pairs_fused
+
+    B, n, W = 4, 16896, 128
+    kern = banded_pairs_fused if fused else banded_forward_pallas
+    _compile(lambda a, b, l, s: kern(a, b, l, s, gap_open=3, gap_extend=1,
+                                     band=W, interpret=False),
+             one_chip, ((B, n), jnp.int8), ((B, n), jnp.int8),
+             ((B, 2), jnp.int32), (SUB, jnp.float32))
+
+
+def test_distance_tile(one_chip):
+    """Distance match/valid counts at (1024, 16896): 1,024 rows of an
+    mtDNA-width MSA."""
+    from repro.kernels.distance import match_valid_pallas
+
+    N, L = 1024, 16896
+    _compile(lambda a, b: match_valid_pallas(a, b, n_chars=5, gap_code=5,
+                                             interpret=False),
+             one_chip, ((N, L), jnp.int8), ((N, L), jnp.int8))
