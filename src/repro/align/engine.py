@@ -50,24 +50,25 @@ _M_FALLBACK = _obs.counter(
     "repro_align_fallback_pairs_total",
     "pairs re-aligned with full DP after band overflow", ("backend",))
 _M_CELLS = _obs.counter(
-    "repro_align_cells_total", "useful DP cells dispatched", ("api",))
+    "repro_align_cells_total",
+    "useful DP cells: each real pair's query length x target length",
+    ("api",))
 _M_PAD_CELLS = _obs.counter(
-    "repro_align_pad_cells_total", "padding DP cells dispatched", ("api",))
-_G_PAD_WASTE = _obs.gauge(
-    "repro_align_pad_waste_ratio",
-    "padding fraction of the last dispatch's DP area", ("api",))
+    "repro_align_pad_cells_total",
+    "DP cells dispatched beyond the useful ones: width padding, chunk "
+    "duplicates and full-DP fallback rows", ("api",))
 
 
 def _record_dispatch(api: str, backend: str, n_calls: int, n_pairs: int,
-                     real_cells: Optional[int],
-                     padded_cells: Optional[int]) -> None:
+                     useful: int, dispatched: int) -> None:
+    """Cells count as the calls were shaped: ``dispatched`` is the sum over
+    every row handed to a backend of its (query width x target width)
+    rectangle (a banded call fills only its band of it), ``useful`` the
+    part of it that real pairs need, counted once per pair."""
     _M_CALLS.labels(api=api, backend=backend).inc(n_calls)
     _M_PAIRS.labels(api=api, backend=backend).inc(n_pairs)
-    if real_cells is None or padded_cells is None or padded_cells <= 0:
-        return
-    _M_CELLS.labels(api=api).inc(real_cells)
-    _M_PAD_CELLS.labels(api=api).inc(max(padded_cells - real_cells, 0))
-    _G_PAD_WASTE.labels(api=api).set(1.0 - real_cells / padded_cells)
+    _M_CELLS.labels(api=api).inc(useful)
+    _M_PAD_CELLS.labels(api=api).inc(dispatched - useful)
 
 
 class EngineResult(NamedTuple):
@@ -204,20 +205,19 @@ class AlignEngine:
 
         if not self.bucket or B == 0:
             _record_dispatch("to_center", self.backend, 1 if B else 0, B,
-                             None, None)
+                             self._useful_cells(lens, lb), B * Lmax * m)
             out = fn(Q, lens, b, lb)
             return self._apply_fallback(out, Q, lens, b, lb, P)
 
         lens_np = np.asarray(lens)
-        real_cells = int(lens_np.sum()) * m
         plan = bucketing.bucket_plan(lens_np, Lmax,
                                      min_bucket=self.min_bucket)
-        padded_cells = sum(width * len(idx) for width, idx in plan) * m
         calls = [(width, chunk) for width, idx in plan
                  for chunk in self._chunks(idx, width, m,
                                              full_dp=not self._is_banded)]
         _record_dispatch("to_center", self.backend, len(calls), B,
-                         real_cells, padded_cells)
+                         self._useful_cells(lens_np, lb),
+                         sum(width * len(idx) for width, idx in calls) * m)
         if len(calls) == 1:
             width, _ = calls[0]
             out = fn(Q[:, :width], lens, b, lb)
@@ -241,6 +241,12 @@ class AlignEngine:
         return self._apply_fallback(merged, Q, lens, b, lb, P)
 
     @staticmethod
+    def _useful_cells(lens, lb) -> int:
+        """Σ query length x center length: the DP cells real pairs need."""
+        return (int(np.asarray(lens, np.int64).sum())
+                * int(np.asarray(lb)))
+
+    @staticmethod
     def _chunks(idx: np.ndarray, n: int, m: int, *, full_dp: bool) -> list:
         """Split one bucket's pair indices so no full-DP call holds more
         than ``DIRS_BUDGET_BYTES`` of (n, m+1) direction matrices. Every
@@ -258,7 +264,10 @@ class AlignEngine:
 
     def _apply_fallback(self, out: backends.BatchAlignment, Q, lens, b, lb,
                         P: int) -> EngineResult:
-        """Re-align pairs the backend flagged (band overflow) with full DP."""
+        """Re-align pairs the backend flagged (band overflow) with full DP.
+
+        Every fallback row's cells count as padding: the pair's useful
+        cells were counted at its first dispatch."""
         bad = np.flatnonzero(~np.asarray(out.ok))
         score = out.score
         a_rows = _pad_cols(out.a_row, P, self.gap_code)
@@ -266,9 +275,10 @@ class AlignEngine:
         aln_len = out.aln_len
         if len(bad):
             _M_FALLBACK.labels(backend=self.backend).inc(len(bad))
-            for chunk in self._chunks(bad, Q.shape[1], b.shape[0],
-                                     full_dp=True):
-                _M_CALLS.labels(api="to_center", backend=self.backend).inc()
+            n, m = Q.shape[1], b.shape[0]
+            for chunk in self._chunks(bad, n, m, full_dp=True):
+                _record_dispatch("to_center", self.backend, 1, 0, 0,
+                                 len(chunk) * n * m)
                 ix = jnp.asarray(chunk)
                 res = self._full_dp_fn()(Q[ix], lens[ix], b, lb)
                 score = score.at[ix].set(res.score)
@@ -359,16 +369,17 @@ class AlignEngine:
             return PairsResult(z, r, r, jnp.zeros((0,), jnp.int32), 0, 0)
         fn = self.pairs_fn()
 
-        if not self.bucket:
-            _record_dispatch("pairs", self.backend, 1, B, None, None)
-            out = fn(Q, qlens, T, tlens)
-            return self._apply_pairs_fallback(out, Q, qlens, T, tlens, P,
-                                              n_calls=1)
-
         qlens_np = np.asarray(qlens)
         tlens_np = np.asarray(tlens)
         real_cells = int((qlens_np.astype(np.int64)
                           * tlens_np.astype(np.int64)).sum())
+
+        if not self.bucket:
+            _record_dispatch("pairs", self.backend, 1, B, real_cells,
+                             B * Lq * Lt)
+            out = fn(Q, qlens, T, tlens)
+            return self._apply_pairs_fallback(out, Q, qlens, T, tlens, P,
+                                              n_calls=1)
 
         if self.band_policy == "adaptive" and self._is_banded:
             # Band-aware buckets: pairs sharing (wq, wt, W) share one
@@ -432,7 +443,9 @@ class AlignEngine:
     def _apply_pairs_fallback(self, out: backends.BatchAlignment, Q, qlens,
                               T, tlens, P: int, *, n_calls: int
                               ) -> PairsResult:
-        """Full-DP re-alignment of pairs the backend flagged (band overflow)."""
+        """Full-DP re-alignment of pairs the backend flagged (band
+        overflow); its rows' cells count as padding, as in
+        ``_apply_fallback``."""
         bad = np.flatnonzero(~np.asarray(out.ok))
         score = out.score
         a_rows = _pad_cols(out.a_row, P, self.gap_code)
@@ -440,7 +453,8 @@ class AlignEngine:
         aln_len = out.aln_len
         if len(bad):
             _M_FALLBACK.labels(backend=self.backend).inc(len(bad))
-            _M_CALLS.labels(api="pairs", backend=self.backend).inc()
+            _record_dispatch("pairs", self.backend, 1, 0, 0,
+                             len(bad) * Q.shape[1] * T.shape[1])
             ix = jnp.asarray(bad)
             res = self._full_dp_pairs_fn()(Q[ix], qlens[ix], T[ix], tlens[ix])
             score = score.at[ix].set(res.score)
@@ -455,7 +469,8 @@ class AlignEngine:
 
         This replaces the old host-numpy round-trip in ``core.msa``: the
         assembled k-mer rows stay on device; only the (B,) ok flags cross
-        to host to pick the failed subset.
+        to host to pick the failed subset (``core.msa`` reads them first,
+        at the end of its ``map1.chain`` span, and passes them as NumPy).
 
         Returns (a_rows, b_rows, n_fallback); widths grow to fit the DP
         rows if needed.

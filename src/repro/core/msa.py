@@ -31,7 +31,13 @@ import numpy as np
 
 from . import alphabet as ab
 from . import centerstar, kmer_index, pairwise
+from ..obs import metrics as _obs
 from ..obs import trace as _trace
+
+_M_CHAIN = _obs.counter(
+    "repro_kmer_chain_pairs_total",
+    "k-mer chained pairs in map(1), by outcome (failed pairs take the "
+    "full DP)", ("outcome",))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,19 +183,38 @@ def map1_align_to_center(Q, qlens, center, lc, cfg: MSAConfig, engine=None):
     so incremental alignment of *new* sequences runs the exact same code
     path as a full realign — the bit-identity the serve tests pin depends
     on it.
+
+    Two spans split the stage: ``map1.chain`` (k-mer method only: the
+    center index, the chaining and the host read of the per-pair ``ok``
+    flags, which waits for the chaining's device work) and ``map1.dp``
+    (the full-DP work, ended on the rows only when the span is recorded).
     """
     gap = cfg.alpha().gap_code
     sub = cfg.matrix()
     engine = cfg.engine() if engine is None else engine
     if cfg.method == "kmer":
-        table = kmer_index.build_center_index(center, lc, k=cfg.k)
-        a_rows, b_rows, ok = kmer_align_batch(
-            Q, qlens, center, lc, table, sub, k=cfg.k, stride=cfg.stride,
-            max_anchors=cfg.max_anchors, max_seg=cfg.max_seg,
-            gap_open=cfg.gap_open, gap_extend=cfg.gap_extend, gap_code=gap)
+        with _trace.span("map1.chain", n=int(Q.shape[0])):
+            table = kmer_index.build_center_index(center, lc, k=cfg.k)
+            a_rows, b_rows, ok = kmer_align_batch(
+                Q, qlens, center, lc, table, sub, k=cfg.k, stride=cfg.stride,
+                max_anchors=cfg.max_anchors, max_seg=cfg.max_seg,
+                gap_open=cfg.gap_open, gap_extend=cfg.gap_extend,
+                gap_code=gap)
+            ok = np.asarray(ok)
+        n_kept = int(ok.sum())
+        _M_CHAIN.labels(outcome="kept").inc(n_kept)
+        _M_CHAIN.labels(outcome="failed").inc(len(ok) - n_kept)
         # chain failures re-align through the engine; rows stay on device
-        return engine.realign_failed(Q, qlens, center, lc, a_rows, b_rows, ok)
-    res = engine.align_to_center(Q, qlens, center, lc)
+        with _trace.span("map1.dp", n=len(ok) - n_kept) as sp:
+            a_rows, b_rows, n_fallback = engine.realign_failed(
+                Q, qlens, center, lc, a_rows, b_rows, ok)
+            if sp is not None:
+                jax.block_until_ready((a_rows, b_rows))
+        return a_rows, b_rows, n_fallback
+    with _trace.span("map1.dp", n=int(Q.shape[0])) as sp:
+        res = engine.align_to_center(Q, qlens, center, lc)
+        if sp is not None:
+            jax.block_until_ready((res.a_row, res.b_row))
     return res.a_row, res.b_row, res.n_fallback
 
 
