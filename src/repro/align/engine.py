@@ -39,6 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import backends, bucketing
+from ..kernels import round_up
+from ..kernels.sw.ops import PAIRS_PER_PROGRAM
 from ..obs import metrics as _obs
 
 _M_CALLS = _obs.counter(
@@ -56,15 +58,16 @@ _M_CELLS = _obs.counter(
 _M_PAD_CELLS = _obs.counter(
     "repro_align_pad_cells_total",
     "DP cells dispatched beyond the useful ones: width padding, chunk "
-    "duplicates and full-DP fallback rows", ("api",))
+    "duplicates, empty SW kernel slots and full-DP fallback rows", ("api",))
 
 
 def _record_dispatch(api: str, backend: str, n_calls: int, n_pairs: int,
                      useful: int, dispatched: int) -> None:
     """Cells count as the calls were shaped: ``dispatched`` is the sum over
     every row handed to a backend of its (query width x target width)
-    rectangle (a banded call fills only its band of it), ``useful`` the
-    part of it that real pairs need, counted once per pair."""
+    rectangle (a banded call fills only its band of it; a ``pallas`` call's
+    rows include its empty group slots, ``AlignEngine._slots``), ``useful``
+    the part of it that real pairs need, counted once per pair."""
     _M_CALLS.labels(api=api, backend=backend).inc(n_calls)
     _M_PAIRS.labels(api=api, backend=backend).inc(n_pairs)
     _M_CELLS.labels(api=api).inc(useful)
@@ -134,6 +137,15 @@ class AlignEngine:
     @property
     def _is_banded(self) -> bool:
         return self.backend in ("banded", "banded-pallas")
+
+    def _slots(self, rows: int) -> int:
+        """Rows a call's DP work covers. The ``pallas`` kernel runs pairs
+        in groups of ``PAIRS_PER_PROGRAM``, one per sublane, and a group's
+        vector work is the same however many of its slots are filled, so
+        a call counts its rows rounded up to whole groups."""
+        if self.backend != "pallas":
+            return rows
+        return round_up(rows, PAIRS_PER_PROGRAM)
 
     def batch_fn(self, *, local: Optional[bool] = None):
         """(Q, lens, b, lb) -> BatchAlignment, safe inside jit/shard_map.
@@ -205,7 +217,8 @@ class AlignEngine:
 
         if not self.bucket or B == 0:
             _record_dispatch("to_center", self.backend, 1 if B else 0, B,
-                             self._useful_cells(lens, lb), B * Lmax * m)
+                             self._useful_cells(lens, lb),
+                             self._slots(B) * Lmax * m)
             out = fn(Q, lens, b, lb)
             return self._apply_fallback(out, Q, lens, b, lb, P)
 
@@ -217,7 +230,8 @@ class AlignEngine:
                                              full_dp=not self._is_banded)]
         _record_dispatch("to_center", self.backend, len(calls), B,
                          self._useful_cells(lens_np, lb),
-                         sum(width * len(idx) for width, idx in calls) * m)
+                         sum(width * self._slots(len(idx))
+                             for width, idx in calls) * m)
         if len(calls) == 1:
             width, _ = calls[0]
             out = fn(Q[:, :width], lens, b, lb)
@@ -376,7 +390,7 @@ class AlignEngine:
 
         if not self.bucket:
             _record_dispatch("pairs", self.backend, 1, B, real_cells,
-                             B * Lq * Lt)
+                             self._slots(B) * Lq * Lt)
             out = fn(Q, qlens, T, tlens)
             return self._apply_pairs_fallback(out, Q, qlens, T, tlens, P,
                                               n_calls=1)
@@ -415,7 +429,8 @@ class AlignEngine:
         plan = bucketing.pair_bucket_plan(qlens_np, tlens_np, Lq, Lt,
                                           min_bucket=self.min_bucket)
         _record_dispatch("pairs", self.backend, len(plan), B, real_cells,
-                         sum(wq * wt * len(idx) for wq, wt, idx in plan))
+                         sum(wq * wt * self._slots(len(idx))
+                             for wq, wt, idx in plan))
         if len(plan) == 1:
             wq, wt, _ = plan[0]
             out = fn(Q[:, :wq], qlens, T[:, :wt], tlens)

@@ -10,7 +10,13 @@ import jax.numpy as jnp
 
 from ...core.pairwise import ForwardResult
 from .. import LANES, round_up
-from .sw_kernel import gotoh_forward_kernel
+from .sw_kernel import PAIRS_PER_PROGRAM, gotoh_forward_kernel, group_size
+
+__all__ = ["PAIRS_PER_PROGRAM", "gotoh_forward_pallas"]
+
+# int32 direction staging one program may hold in VMEM (G pairs x block
+# rows x Mp); block_rows shrinks to fit it at long target widths
+_STAGING_BYTES = 16 << 20
 
 
 @functools.partial(jax.jit, static_argnames=("gap_open", "gap_extend", "local",
@@ -30,9 +36,11 @@ def gotoh_forward_pallas(a, b, lens, sub, *, gap_open, gap_extend,
     B, n = a.shape
     m = b.shape[1]
     Mp = round_up(m + 1, LANES)
-    # row blocks: a multiple of 32 (the int8 tile height), or one block
-    # covering the whole (8-row padded) query
-    br = min(round_up(block_rows, 32), round_up(max(n, 1), 8))
+    # row blocks: a multiple of 32 (the int8 tile height) whose staging
+    # fits _STAGING_BYTES, or one block covering the whole (8-row padded)
+    # query
+    fit = max(32, _STAGING_BYTES // (group_size(B) * Mp * 4) // 32 * 32)
+    br = min(round_up(block_rows, 32), fit, round_up(max(n, 1), 8))
     a = jnp.pad(a.astype(jnp.int32), ((0, 0), (0, (-n) % br)))
     sub = sub.astype(jnp.float32)
     # prof[p, c, j] = sub[c, b[p, j-1]]; column 0 and the lane padding are 0

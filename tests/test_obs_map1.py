@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.align import AlignEngine
+from repro.align import backends
 from repro.align import engine as engine_mod
 from repro.core import alphabet as ab
 from repro.core.msa import MSAConfig, center_star_msa
@@ -139,3 +140,59 @@ def test_band_overflow_fallback_adds_its_cells(api):
     assert useful == 24 * 54
     # the banded call's 64 x 64 rectangle, then the full-DP fallback's
     assert useful + pad == 64 * 64 + 64 * 64
+
+
+def test_pallas_group_slots_count_as_pad():
+    """The pallas kernel runs pairs 8 to a program: 7 queries in one call
+    dispatch 8 slots' cells, and the useful cells are the jnp backend's."""
+    lens = np.array([40, 39, 37, 36, 34, 40, 33], np.int32)
+    rng = np.random.default_rng(5)
+    Q = rng.integers(0, 4, (7, 40)).astype(np.int8)
+    b = np.full(40, 5, np.int8)
+    b[:38] = rng.integers(0, 4, 38)
+    got = {}
+    for backend in ("jnp", "pallas"):
+        eng = AlignEngine(SUB, gap_open=3, gap_extend=1, gap_code=5,
+                          backend=backend)
+        before = _cells("to_center")
+        eng.align_to_center(Q, lens, b, jnp.int32(38))
+        got[backend] = tuple(x - x0 for x, x0 in
+                             zip(_cells("to_center"), before))
+    useful, pad = got["pallas"]
+    assert useful == got["jnp"][0] == int(lens.sum()) * 38
+    assert sum(got["jnp"]) == 7 * 40 * 40
+    assert useful + pad == 8 * 40 * 40
+
+
+@pytest.mark.parametrize("n_pairs,length,calls,slots", [
+    (1023, 1500, 2, 1920),       # rrna16s-nj: 2 chunks of 953 -> 960 slots
+    (95, 16569, 14, 112),        # mtdna-msa: 14 chunks of 7 -> 8 slots
+])
+def test_pallas_cell_plans_count_group_slots(monkeypatch, n_pairs, length,
+                                             calls, slots):
+    """The benchmark cells' map(1) plans on the pallas backend, with the
+    kernel call stubbed out: the direction budget splits the pairs into
+    equal chunks, and each chunk counts its rows rounded up to whole
+    8-pair groups."""
+    def stub(Q, lens, b, lb, sub, **_):
+        B, P = Q.shape[0], Q.shape[1] + b.shape[0]
+        rows = jnp.zeros((B, P), jnp.int8)
+        return backends.BatchAlignment(jnp.zeros((B,), jnp.float32), rows,
+                                       rows, jnp.zeros((B,), jnp.int32),
+                                       jnp.ones((B,), jnp.bool_))
+
+    monkeypatch.setattr(backends, "pallas_align_batch", stub)
+    eng = AlignEngine(SUB, gap_open=3, gap_extend=1, gap_code=5,
+                      backend="pallas")
+    Q = np.zeros((n_pairs, length), np.int8)
+    lens = np.full(n_pairs, length, np.int32)
+    calls0 = _value("repro_align_calls_total", api="to_center",
+                    backend="pallas")
+    before = _cells("to_center")
+    eng.align_to_center(Q, lens, np.zeros(length, np.int8),
+                        jnp.int32(length))
+    useful, pad = (x - x0 for x, x0 in zip(_cells("to_center"), before))
+    assert _value("repro_align_calls_total", api="to_center",
+                  backend="pallas") - calls0 == calls
+    assert useful == n_pairs * length * length
+    assert useful + pad == slots * length * length

@@ -55,6 +55,19 @@ def test_sw_forward_16s(one_chip, local):
         ((B, 2), jnp.int32), (SUB, jnp.float32))
 
 
+@pytest.mark.parametrize("B,n", [(7, 16569), (953, 1500)])
+def test_sw_forward_cells(one_chip, B, n):
+    """SW forward (global) at the benchmark cells' calls: mtdna-msa's 7
+    pairs at 16,569 bp (one partial 8-pair group, 32-row blocks) and
+    rrna16s-nj's 953 pairs at 1,500 bp (119 full groups and one of 1)."""
+    from repro.kernels.sw.ops import gotoh_forward_pallas
+
+    _compile(lambda a, b, l, s: gotoh_forward_pallas(
+        a, b, l, s, gap_open=3, gap_extend=1, local=False, interpret=False),
+        one_chip, ((B, n), jnp.int8), ((B, n), jnp.int8),
+        ((B, 2), jnp.int32), (SUB, jnp.float32))
+
+
 @pytest.mark.parametrize("fused", [False, True])
 def test_banded_mtdna(one_chip, fused):
     """Banded forward and fused pairs at mtDNA length padded to 128
