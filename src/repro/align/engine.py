@@ -216,22 +216,19 @@ class AlignEngine:
         fn = self.batch_fn()
 
         if not self.bucket or B == 0:
-            _record_dispatch("to_center", self.backend, 1 if B else 0, B,
-                             self._useful_cells(lens, lb),
-                             self._slots(B) * Lmax * m)
+            self.record_to_center(1 if B else 0, B, lens, lb, Lmax, m)
             out = fn(Q, lens, b, lb)
             return self._apply_fallback(out, Q, lens, b, lb, P)
 
         lens_np = np.asarray(lens)
         plan = bucketing.bucket_plan(lens_np, Lmax,
                                      min_bucket=self.min_bucket)
-        calls = [(width, chunk) for width, idx in plan
-                 for chunk in self._chunks(idx, width, m,
-                                             full_dp=not self._is_banded)]
-        _record_dispatch("to_center", self.backend, len(calls), B,
-                         self._useful_cells(lens_np, lb),
-                         sum(width * self._slots(len(idx))
-                             for width, idx in calls) * m)
+        calls = []
+        for width, idx in plan:
+            chunks = self._chunks(idx, width, m, full_dp=not self._is_banded)
+            self.record_to_center(len(chunks), len(chunks[0]), lens_np[idx],
+                                  lb, width, m)
+            calls += [(width, chunk) for chunk in chunks]
         if len(calls) == 1:
             width, _ = calls[0]
             out = fn(Q[:, :width], lens, b, lb)
@@ -253,6 +250,16 @@ class AlignEngine:
         merged = backends.BatchAlignment(score, a_rows, b_rows, aln_len,
                                          jnp.asarray(ok))
         return self._apply_fallback(merged, Q, lens, b, lb, P)
+
+    def record_to_center(self, n_calls: int, rows: int, lens, lb,
+                         width: int, m: int) -> None:
+        """Count ``n_calls`` map(1) calls of ``rows`` rows each, every row
+        a (``width`` x ``m``) rectangle, that align the real queries of
+        lengths ``lens`` to a target of length ``lb``: the host's calls of
+        one bucket, or every shard's every chunk on the mesh."""
+        _record_dispatch("to_center", self.backend, n_calls, len(lens),
+                         self._useful_cells(lens, lb),
+                         n_calls * self._slots(rows) * width * m)
 
     @staticmethod
     def _useful_cells(lens, lb) -> int:

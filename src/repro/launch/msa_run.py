@@ -146,8 +146,7 @@ def _run(args):
               "center_mode": res.center_mode,
               "backend": resolve_backend(args.backend),
               "avg_sp_penalty": sp,
-              # null under --dist: per-pair fallbacks aren't tracked there
-              "kmer_fallbacks": res.n_fallback if res.n_fallback >= 0 else None,
+              "kmer_fallbacks": res.n_fallback,
               "msa_seconds": t_msa}
 
     if args.tree != "none":

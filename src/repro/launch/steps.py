@@ -169,7 +169,10 @@ MSA_CELLS = {
 
 
 def build_msa_step(cell: str, mesh):
-    """Lower the distributed center-star MSA (the paper's own workload)."""
+    """The distributed center-star MSA's DP + reduce(1) + map(2) program
+    (the paper's own workload; it holds the pipeline's one collective) and
+    its argument shapes, the k-mer method's chained rows from
+    ``jax.eval_shape`` of the chain program."""
     import jax.numpy as jnp
 
     from ..core import alphabet as ab
@@ -191,5 +194,6 @@ def build_msa_step(cell: str, mesh):
     lc = jax.ShapeDtypeStruct((), jnp.int32)
     if method == "kmer":
         table = jax.ShapeDtypeStruct((4 ** (k or 11), 4), jnp.int32)
-        return fn, (Q, lens, center, lc, table)
-    return fn, (Q, lens, center, lc)
+        chained = jax.eval_shape(fn.chain, Q, lens, center, lc, table)
+        return fn.align, (Q, lens, center, lc, *chained)
+    return fn.align, (Q, lens, center, lc)

@@ -129,12 +129,13 @@ def test_distributed_center_star_equals_host_version(dna_family):
     table = kmer_index.build_center_index(center, lc, k=8)
     sub = ab.dna_matrix().astype(jnp.float32)
 
-    fn = mapreduce.distributed_center_star(
+    progs = mapreduce.distributed_center_star(
         mesh, method="kmer", sub=sub, gap_code=ab.DNA.gap_code,
         out_len=400, num_slots=int(center.shape[0]) + 1, gap_open=3,
         gap_extend=1, k=8, max_anchors=96, max_seg=48)
-    rows, G = fn(sh.shard_rows(S, mesh), sh.shard_rows(lens, mesh),
-                 sh.broadcast(center, mesh), lc, sh.broadcast(table, mesh))
+    ops = (sh.shard_rows(S, mesh), sh.shard_rows(lens, mesh),
+           sh.broadcast(center, mesh), lc)
+    rows, G = progs.align(*ops, *progs.chain(*ops, sh.broadcast(table, mesh)))
     for s, r in zip(seqs, np.asarray(rows)):
         assert ab.DNA.decode(r).replace("-", "") == s
 

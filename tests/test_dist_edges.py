@@ -166,13 +166,14 @@ def test_padded_queries_align_as_all_gap(dna_family):
     center = jnp.asarray(ab.DNA.encode(center_s))
     lc = jnp.int32(len(center_s))
     table = kmer_index.build_center_index(center, lc, k=8)
-    fn = mapreduce.distributed_center_star(
+    progs = mapreduce.distributed_center_star(
         mesh, method="kmer", sub=ab.dna_matrix().astype(jnp.float32),
         gap_code=ab.DNA.gap_code, out_len=400,
         num_slots=int(center.shape[0]) + 1, gap_open=3, gap_extend=1, k=8,
         max_anchors=96, max_seg=48)
-    rows, G = fn(sh.shard_rows(Q, mesh), sh.shard_rows(qlens, mesh),
-                 sh.broadcast(center, mesh), lc, sh.broadcast(table, mesh))
+    ops = (sh.shard_rows(Q, mesh), sh.shard_rows(qlens, mesh),
+           sh.broadcast(center, mesh), lc)
+    rows, G = progs.align(*ops, *progs.chain(*ops, sh.broadcast(table, mesh)))
     rows = np.asarray(rows)
     for s, r in zip(seqs, rows[:n_q]):
         assert ab.DNA.decode(r).replace("-", "") == s

@@ -41,12 +41,13 @@ center = jnp.asarray(ab.DNA.encode(base)); lc = jnp.int32(len(base))
 table = kmer_index.build_center_index(center, lc, k=8)
 sub = ab.dna_matrix().astype(jnp.float32)
 mesh = make_local_mesh((4, 2), ("data", "model"))
-fn = mapreduce.distributed_center_star(
+progs = mapreduce.distributed_center_star(
     mesh, method="kmer", sub=sub, gap_code=ab.DNA.gap_code, out_len=300,
     num_slots=int(center.shape[0]) + 1, gap_open=3, gap_extend=1, k=8,
     max_anchors=64, max_seg=48)
-rows, G = fn(sh.shard_rows(S, mesh), sh.shard_rows(lens, mesh),
-             sh.broadcast(center, mesh), lc, sh.broadcast(table, mesh))
+ops = (sh.shard_rows(S, mesh), sh.shard_rows(lens, mesh),
+       sh.broadcast(center, mesh), lc)
+rows, G = progs.align(*ops, *progs.chain(*ops, sh.broadcast(table, mesh)))
 ok = all(ab.DNA.decode(r).replace("-", "") == s
          for s, r in zip(seqs, np.asarray(rows)))
 out["msa_distributed_ok"] = bool(ok)

@@ -91,3 +91,34 @@ def test_distance_tile(one_chip):
     _compile(lambda a, b: match_valid_pallas(a, b, n_chars=5, gap_code=5,
                                              interpret=False),
              one_chip, ((N, L), jnp.int8), ((N, L), jnp.int8))
+
+
+def test_mesh_chain_calls_fit_the_budget(one_chip):
+    """The mesh's chain program at 840 rows a chip (the mtDNA set
+    replicated 5x on four chips) splits into calls whose temporaries fit
+    ``DIRS_BUDGET_BYTES``; ``chain_row_bytes`` bounds what one row holds."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.align.engine import DIRS_BUDGET_BYTES
+    from repro.dist import mapreduce
+
+    rows, L = 840, 16569
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    progs = mapreduce.distributed_center_star(
+        mesh, method="kmer", sub=jnp.asarray(ab.dna_matrix()), gap_code=5,
+        out_len=2 * L + 64, num_slots=L + 1, gap_open=3, gap_extend=1)
+
+    def sd(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    temps = progs.chain.lower(
+        sd((rows, L), jnp.int8, P("data", None)),
+        sd((rows,), jnp.int32, P("data")), sd((L,), jnp.int8, P()),
+        sd((), jnp.int32, P()), sd((4 ** 11, 4), jnp.int32, P()),
+    ).compile().memory_analysis().temp_size_in_bytes
+    per_call = -(-rows // -(-rows // (DIRS_BUDGET_BYTES
+                                      // mapreduce.chain_row_bytes(256, 64))))
+    assert temps <= DIRS_BUDGET_BYTES
+    assert temps <= per_call * mapreduce.chain_row_bytes(256, 64)
