@@ -9,9 +9,10 @@ full-DP re-alignment — only the ``banded`` backend ever clears it).
 
   jnp            the row-scan Gotoh oracle (``core.pairwise``); O(n·m)
                  dirs
-  pallas         the ``kernels.sw`` Pallas kernel (compiled on TPU,
-                 interpreted elsewhere) + the shared traceback; O(n·m)
-                 dirs in HBM, row scores never leave VMEM
+  pallas         the ``kernels.sw`` Pallas kernels (compiled on TPU,
+                 interpreted elsewhere): the forward, O(n·m) dirs in HBM
+                 with the row scores never leaving VMEM, then the
+                 traceback kernel walking those dirs from VMEM windows
   banded         diagonal band as a jnp scan, O(n·W) dirs, per-pair
                  overflow flags
   banded-pallas  the same band as a native Pallas kernel
@@ -41,7 +42,7 @@ import jax.numpy as jnp
 
 from ..core import pairwise
 from ..kernels.banded.ops import banded_forward_pallas, banded_pairs_fused
-from ..kernels.sw.ops import gotoh_forward_pallas
+from ..kernels.sw.ops import gotoh_forward_pallas, sw_traceback_pallas
 from . import banded as banded_mod
 
 
@@ -78,8 +79,8 @@ def pallas_align_batch(Q, lens, b, lb, sub, *, gap_open, gap_extend,
                                gap_extend=gap_extend, local=local,
                                block_rows=min(block_rows, max(n, 1)),
                                interpret=interpret)
-    a_row, b_row, k = jax.vmap(
-        lambda a_, b_, f: pairwise.traceback(a_, b_, f, gap_code))(Q, Bm, fwd)
+    a_row, b_row, k = sw_traceback_pallas(Q, Bm, fwd, gap_code=gap_code,
+                                          interpret=interpret)
     return BatchAlignment(fwd.score, a_row, b_row, k,
                           jnp.ones(B, jnp.bool_))
 
@@ -124,8 +125,8 @@ def pallas_align_pairs(Q, qlens, T, tlens, sub, *, gap_open, gap_extend,
                                gap_extend=gap_extend, local=local,
                                block_rows=min(block_rows, max(n, 1)),
                                interpret=interpret)
-    a_row, b_row, k = jax.vmap(
-        lambda a_, b_, f: pairwise.traceback(a_, b_, f, gap_code))(Q, T, fwd)
+    a_row, b_row, k = sw_traceback_pallas(Q, T, fwd, gap_code=gap_code,
+                                          interpret=interpret)
     return BatchAlignment(fwd.score, a_row, b_row, k,
                           jnp.ones(B, jnp.bool_))
 

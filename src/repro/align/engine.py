@@ -59,19 +59,27 @@ _M_PAD_CELLS = _obs.counter(
     "repro_align_pad_cells_total",
     "DP cells dispatched beyond the useful ones: width padding, chunk "
     "duplicates, empty SW kernel slots and full-DP fallback rows", ("api",))
+_M_WALKED = _obs.counter(
+    "repro_align_traceback_rows_total",
+    "rows walked back, as dispatched: by a traceback kernel (the SW "
+    "traceback, the fused banded pairs kernel) or by a jnp scan", ("walk",))
 
 
 def _record_dispatch(api: str, backend: str, n_calls: int, n_pairs: int,
-                     useful: int, dispatched: int) -> None:
+                     useful: int, dispatched: int, *, walk: str,
+                     walked: int) -> None:
     """Cells count as the calls were shaped: ``dispatched`` is the sum over
     every row handed to a backend of its (query width x target width)
     rectangle (a banded call fills only its band of it; a ``pallas`` call's
     rows include its empty group slots, ``AlignEngine._slots``), ``useful``
-    the part of it that real pairs need, counted once per pair."""
+    the part of it that real pairs need, counted once per pair. ``walked``
+    rows (chunk duplicates included, empty kernel slots not) are walked
+    back by ``walk`` (``AlignEngine._walk``)."""
     _M_CALLS.labels(api=api, backend=backend).inc(n_calls)
     _M_PAIRS.labels(api=api, backend=backend).inc(n_pairs)
     _M_CELLS.labels(api=api).inc(useful)
     _M_PAD_CELLS.labels(api=api).inc(dispatched - useful)
+    _M_WALKED.labels(walk=walk).inc(walked)
 
 
 class EngineResult(NamedTuple):
@@ -146,6 +154,16 @@ class AlignEngine:
         if self.backend != "pallas":
             return rows
         return round_up(rows, PAIRS_PER_PROGRAM)
+
+    def _walk(self, api: str, *, fallback: bool = False) -> str:
+        """What walks a call's rows back: the SW traceback kernel on
+        ``pallas``, the fused kernel on ``banded-pallas`` pairs, a jnp
+        scan elsewhere (a banded engine's full-DP fallback runs ``jnp``)."""
+        if self.backend == "pallas" or (
+                self.backend == "banded-pallas" and api == "pairs"
+                and not fallback):
+            return "kernel"
+        return "scan"
 
     def batch_fn(self, *, local: Optional[bool] = None):
         """(Q, lens, b, lb) -> BatchAlignment, safe inside jit/shard_map.
@@ -259,7 +277,8 @@ class AlignEngine:
         one bucket, or every shard's every chunk on the mesh."""
         _record_dispatch("to_center", self.backend, n_calls, len(lens),
                          self._useful_cells(lens, lb),
-                         n_calls * self._slots(rows) * width * m)
+                         n_calls * self._slots(rows) * width * m,
+                         walk=self._walk("to_center"), walked=n_calls * rows)
 
     @staticmethod
     def _useful_cells(lens, lb) -> int:
@@ -299,7 +318,9 @@ class AlignEngine:
             n, m = Q.shape[1], b.shape[0]
             for chunk in self._chunks(bad, n, m, full_dp=True):
                 _record_dispatch("to_center", self.backend, 1, 0, 0,
-                                 len(chunk) * n * m)
+                                 len(chunk) * n * m,
+                                 walk=self._walk("to_center", fallback=True),
+                                 walked=len(chunk))
                 ix = jnp.asarray(chunk)
                 res = self._full_dp_fn()(Q[ix], lens[ix], b, lb)
                 score = score.at[ix].set(res.score)
@@ -397,7 +418,8 @@ class AlignEngine:
 
         if not self.bucket:
             _record_dispatch("pairs", self.backend, 1, B, real_cells,
-                             self._slots(B) * Lq * Lt)
+                             self._slots(B) * Lq * Lt,
+                             walk=self._walk("pairs"), walked=B)
             out = fn(Q, qlens, T, tlens)
             return self._apply_pairs_fallback(out, Q, qlens, T, tlens, P,
                                               n_calls=1)
@@ -411,7 +433,8 @@ class AlignEngine:
                 band=self.band, min_bucket=self.min_bucket)
             _record_dispatch(
                 "pairs", self.backend, len(plan), B, real_cells,
-                sum(wq * wt * len(idx) for wq, wt, _, idx in plan))
+                sum(wq * wt * len(idx) for wq, wt, _, idx in plan),
+                walk=self._walk("pairs"), walked=B)
             score = jnp.zeros((B,), jnp.float32)
             a_rows = jnp.full((B, P), self.gap_code, jnp.int8)
             b_rows = jnp.full((B, P), self.gap_code, jnp.int8)
@@ -437,7 +460,8 @@ class AlignEngine:
                                           min_bucket=self.min_bucket)
         _record_dispatch("pairs", self.backend, len(plan), B, real_cells,
                          sum(wq * wt * self._slots(len(idx))
-                             for wq, wt, idx in plan))
+                             for wq, wt, idx in plan),
+                         walk=self._walk("pairs"), walked=B)
         if len(plan) == 1:
             wq, wt, _ = plan[0]
             out = fn(Q[:, :wq], qlens, T[:, :wt], tlens)
@@ -476,7 +500,9 @@ class AlignEngine:
         if len(bad):
             _M_FALLBACK.labels(backend=self.backend).inc(len(bad))
             _record_dispatch("pairs", self.backend, 1, 0, 0,
-                             len(bad) * Q.shape[1] * T.shape[1])
+                             len(bad) * Q.shape[1] * T.shape[1],
+                             walk=self._walk("pairs", fallback=True),
+                             walked=len(bad))
             ix = jnp.asarray(bad)
             res = self._full_dp_pairs_fn()(Q[ix], qlens[ix], T[ix], tlens[ix])
             score = score.at[ix].set(res.score)

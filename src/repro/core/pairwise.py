@@ -4,8 +4,9 @@ This is the paper's Eq. (1)-(2) engine, vectorized the TPU way: the classic
 cell-by-cell DP is re-expressed as a scan over rows where every in-row
 dependency is either elementwise (M, Ix) or a running max (Iy via cummax), so
 each row is one fused vector op. The Pallas kernel in ``repro.kernels.sw``
-implements the same recurrences with explicit VMEM tiling; this module is the
-jnp oracle and the small-problem workhorse.
+implements the same recurrences with explicit VMEM tiling, and its traceback
+kernel walks the same directions; this module is the jnp oracle and the
+small-problem workhorse.
 
 State convention (shared with the kernel and the traceback):
   M  = 0  a[i-1] aligned to b[j-1]            (diagonal move)
@@ -196,6 +197,33 @@ def traceback(a, b, fwd: ForwardResult, gap_code: int):
     def unrev(x):
         return jnp.roll(jnp.flip(x), k - out_len)
     return unrev(out_a), unrev(out_b), k
+
+
+def rows_from_codes(a, b, start_i, start_j, codes, k, gap_code: int):
+    """Gapped rows of walks recorded as one state code per column.
+
+    a: (B, n), b: (B, m); codes: (B, >= n + m) int, column t (t < k) the
+    state of the walk's t-th step back from (start_i, start_j): M consumes
+    a and b, Ix a, Iy b. Column t of the reversed row carries
+    x[start - 1 - (# earlier columns consuming x)] where its state consumes
+    x, else a gap; un-reversed like ``traceback``, whose rows these equal
+    byte for byte. Returns (a_row, b_row), each (B, n + m) int8.
+    """
+    out_len = a.shape[1] + b.shape[1]
+    codes = codes[:, :out_len].astype(jnp.int32)
+    t = jnp.arange(out_len)
+
+    def row(x, lx, consumes, kk):
+        use = (t < kk) & consumes
+        cnt = jnp.cumsum(use.astype(jnp.int32)) - use
+        idx = jnp.clip(lx - 1 - cnt, 0, x.shape[0] - 1)
+        rev = jnp.where(use, x[idx], gap_code).astype(jnp.int8)
+        return jnp.roll(jnp.flip(rev), kk - out_len)
+
+    is_m = codes == M_ST
+    rows = jax.vmap(row)
+    return (rows(a, start_i, is_m | (codes == IX_ST), k),
+            rows(b, start_j, is_m | (codes == IY_ST), k))
 
 
 @functools.partial(jax.jit, static_argnames=("gap_open", "gap_extend", "local", "gap_code"))

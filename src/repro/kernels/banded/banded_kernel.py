@@ -18,8 +18,10 @@ go to an (n, Wp) VMEM scratch that persists across the row blocks, and the
 last row block walks that scratch back from the end cell. The direction
 matrix never exists in HBM at all: per pair the kernel moves only the
 sequences in and the path out — one int8 state code per alignment column
-(M / Ix / Iy, i.e. which of a and b the column consumes); ``ops`` turns
-the codes into the two gapped rows with a prefix sum and a gather.
+(M / Ix / Iy, i.e. which of a and b the column consumes);
+``core.pairwise.rows_from_codes`` turns the codes into the two gapped
+rows with a prefix sum and a gather, as it does for the SW traceback
+kernel.
 
 Layout for the TPU target (what Mosaic accepts): band rows are (1, Wp)
 with Wp = round_up(W, 128) lanes (lanes past W are held at NEG); the

@@ -6,7 +6,7 @@ returns a batched ``BandedForward`` — drop-in for vmapped
 dirs exactly as before). ``banded_pairs_fused`` is the whole map(1) in
 one kernel: scores, path, lengths, and the ok flag come back with no
 direction matrix ever materialized in HBM; the gapped rows are rebuilt
-from the path's per-column state codes here.
+from the path's per-column state codes (``core.pairwise.rows_from_codes``).
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ...core.pairwise import M_ST, IX_ST, IY_ST
+from ...core.pairwise import rows_from_codes
 from .. import LANES, round_up
 from .banded_kernel import banded_forward_kernel, banded_fused_kernel
 from .ref import BandedForward
@@ -65,19 +65,6 @@ def banded_forward_pallas(a, b, lens, sub, *, gap_open, gap_extend, band,
                          out[:, 4] > 0.5)
 
 
-def _rows_from_codes(x, lx, codes, k, consumes, gap_code: int):
-    """One gapped row from the reversed path: column t (t < k) of the walk
-    carries x[lx - 1 - (# earlier columns consuming x)] when its state
-    consumes x, else a gap; then un-reverse like ``pairwise.traceback``."""
-    out_len = codes.shape[0]
-    t = jnp.arange(out_len)
-    use = (t < k) & consumes
-    cnt = jnp.cumsum(use.astype(jnp.int32)) - use
-    idx = jnp.clip(lx - 1 - cnt, 0, x.shape[0] - 1)
-    rev = jnp.where(use, x[idx], gap_code).astype(jnp.int8)
-    return jnp.roll(jnp.flip(rev), k - out_len)
-
-
 @functools.partial(jax.jit, static_argnames=("gap_open", "gap_extend",
                                              "band", "gap_code",
                                              "block_rows", "interpret"))
@@ -100,9 +87,7 @@ def banded_pairs_fused(a, b, lens, sub, *, gap_open, gap_extend, band,
         block_rows=br, interpret=interpret)
     out = out[:, 0, :]
     k = out[:, 4].astype(jnp.int32)
-    codes = codes.reshape(codes.shape[0], -1)[:, :out_len].astype(jnp.int32)
-    is_m = codes == M_ST
-    rows = jax.vmap(_rows_from_codes, in_axes=(0, 0, 0, 0, 0, None))
-    a_row = rows(a, lens[:, 0], codes, k, is_m | (codes == IX_ST), gap_code)
-    b_row = rows(b, lens[:, 1], codes, k, is_m | (codes == IY_ST), gap_code)
+    a_row, b_row = rows_from_codes(a, b, lens[:, 0], lens[:, 1],
+                                   codes.reshape(codes.shape[0], -1), k,
+                                   gap_code)
     return (out[:, 0], a_row, b_row, k, out[:, 5] > 0.5)

@@ -1,6 +1,7 @@
-"""jit'd public wrapper for the SW/Gotoh kernel: padding, the per-target
-substitution profile, and a drop-in replacement for pairwise.gotoh_forward
-in batch form."""
+"""jit'd public wrappers for the SW/Gotoh kernels: the forward (padding,
+the per-target substitution profile; a drop-in replacement for
+pairwise.gotoh_forward in batch form) and the traceback over its
+directions (a drop-in replacement for a vmapped pairwise.traceback)."""
 from __future__ import annotations
 
 import functools
@@ -8,11 +9,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ...core.pairwise import ForwardResult
+from ...core.pairwise import ForwardResult, rows_from_codes
 from .. import LANES, round_up
 from .sw_kernel import PAIRS_PER_PROGRAM, gotoh_forward_kernel, group_size
+from .traceback_kernel import sw_traceback_kernel
 
-__all__ = ["PAIRS_PER_PROGRAM", "gotoh_forward_pallas"]
+__all__ = ["PAIRS_PER_PROGRAM", "gotoh_forward_pallas", "sw_traceback_pallas"]
 
 # int32 direction staging one program may hold in VMEM (G pairs x block
 # rows x Mp); block_rows shrinks to fit it at long target widths
@@ -29,7 +31,7 @@ def gotoh_forward_pallas(a, b, lens, sub, *, gap_open, gap_extend,
     a: (B, n) int8, b: (B, m) int8, lens: (B, 2) i32 [[la, lb], ...].
     ``dirs`` holds DP rows 1..n (row 0 is closed-form) as a padded
     (B, n_pad, Mp) int8 buffer, Mp = round_up(m+1, 128);
-    ``core.pairwise.traceback`` consumes it as is. ``interpret=None``
+    ``sw_traceback_pallas`` consumes it as is. ``interpret=None``
     resolves platform-aware (compiled on TPU) inside the shared
     ``kernels.kernel_call`` wrapper.
     """
@@ -54,3 +56,23 @@ def gotoh_forward_pallas(a, b, lens, sub, *, gap_open, gap_extend,
     return ForwardResult(dirs, out[:, 0], out[:, 1].astype(jnp.int32),
                          out[:, 2].astype(jnp.int32),
                          out[:, 3].astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("gap_code", "interpret"))
+def sw_traceback_pallas(a, b, fwd: ForwardResult, *, gap_code: int,
+                        interpret: bool | None = None):
+    """Batched traceback with the kernel: vmapped ``pairwise.traceback``'s
+    (a_row, b_row, k), byte for byte.
+
+    a: (B, n) int8, b: (B, m) int8, ``fwd`` the batched result of
+    ``gotoh_forward_pallas`` on them. The kernel walks the directions and
+    returns one state code per column; the rows are built from the codes
+    here (``core.pairwise.rows_from_codes``).
+    """
+    starts = jnp.stack([fwd.start_i, fwd.start_j, fwd.start_state], axis=1)
+    codes, k = sw_traceback_kernel(fwd.dirs, starts,
+                                   out_len=a.shape[1] + b.shape[1],
+                                   interpret=interpret)
+    a_row, b_row = rows_from_codes(a, b, fwd.start_i, fwd.start_j, codes, k,
+                                   gap_code)
+    return a_row, b_row, k

@@ -1,4 +1,5 @@
 """SW/Gotoh Pallas kernel vs jnp oracle: shape/dtype/param sweeps."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -137,3 +138,101 @@ def test_pair_groups_blosum62(local):
     k = _assert_matches_oracle(a, b, lens, sub, go=11, ge=1, local=local,
                                block=32)
     _assert_traceback_decodes(k, a, b, lens, ab.PROTEIN, local)
+
+
+def _walk_case(B, n, m, seed, *, gaps=False, fresh=False):
+    """B pairs for the traceback kernel. Random pairs have a length-0
+    query (start at row 0), a length-0 target (start at column 0) and an
+    empty pair among them; ``gaps`` pairs share a sequence with a long
+    deletion and a long insertion (vertical and horizontal runs across
+    row windows); ``fresh`` pairs embed a shared core in unrelated
+    flanks, so a local path stops on FRESH inside the matrix."""
+    r = np.random.default_rng(seed)
+    A = r.integers(0, 4, (B, n)).astype(np.int8)
+    T = r.integers(0, 4, (B, m)).astype(np.int8)
+    la = r.integers(1, n + 1, B)
+    lb = r.integers(1, m + 1, B)
+    for p in range(B):
+        if gaps:
+            la[p] = r.integers(n // 2, n + 1)
+            d0, dl = r.integers(0, la[p] // 2), r.integers(40, 90)
+            t = np.concatenate([A[p, :d0], A[p, d0 + dl:la[p]]])
+            c = r.integers(0, len(t) + 1)
+            t = np.concatenate([t[:c], r.integers(0, 4, r.integers(40, 80)),
+                                t[c:]])[:m]
+            T[p, :len(t)], lb[p] = t, len(t)
+        elif fresh:
+            core = r.integers(0, 4, n // 2)
+            q0, t0 = r.integers(0, n - len(core)), r.integers(0, m - len(core))
+            A[p, q0:q0 + len(core)] = core
+            T[p, t0:t0 + len(core)] = core
+            la[p], lb[p] = n, m
+    if B > 1 and not (gaps or fresh):
+        la[0] = 0
+        lb[1] = 0
+    if B > 2 and not (gaps or fresh):
+        la[2] = lb[2] = 0
+    return (jnp.asarray(A), jnp.asarray(T),
+            jnp.asarray(np.stack([la, lb], 1).astype(np.int32)))
+
+
+@pytest.mark.parametrize("B,n,m,local,kind", [
+    (B, 64, 80, local, "random") for B in (1, 7, 8, 9, 17)
+    for local in (False, True)] + [
+    (3, 300, 330, False, "gaps"), (9, 300, 330, True, "gaps"),
+    (5, 96, 96, True, "fresh"),
+])
+def test_traceback_kernel_matches_scan(monkeypatch, B, n, m, local, kind):
+    """The traceback kernel's rows and lengths equal vmapped
+    ``pairwise.traceback``'s byte for byte on the forward kernel's
+    directions: partial pair groups (B = 9, 17), empty slots, length-0
+    queries and targets, long gaps across 64-row windows (the window
+    budget forced to its minimum), and local paths that stop on FRESH."""
+    from repro.kernels.sw import ops, traceback_kernel
+
+    a, b, lens = _walk_case(B, n, m, seed=B + 100 * local,
+                            gaps=kind == "gaps", fresh=kind == "fresh")
+    sub = ab.dna_matrix().astype(jnp.float32)
+    fwd = gotoh_forward_pallas(a, b, lens, sub, gap_open=3, gap_extend=1,
+                               local=local, block_rows=32)
+    walk = ops.sw_traceback_pallas
+    if kind == "gaps":
+        monkeypatch.setattr(traceback_kernel, "TRACE_BYTES", 0)
+        assert traceback_kernel.window_rows(*fwd.dirs.shape[1:],
+                                            min(8, B)) == 64
+        walk = walk.__wrapped__          # trace anew under the budget
+    a_row, b_row, k = walk(a, b, fwd, gap_code=ab.DNA.gap_code)
+    ra, rb, rk = jax.vmap(lambda x, y, f: pw.traceback(
+        x, y, f, ab.DNA.gap_code))(a, b, fwd)
+    np.testing.assert_array_equal(np.asarray(k), np.asarray(rk))
+    np.testing.assert_array_equal(np.asarray(a_row), np.asarray(ra))
+    np.testing.assert_array_equal(np.asarray(b_row), np.asarray(rb))
+    if kind == "fresh":
+        # some walk stopped inside the matrix, short of row and column 0
+        used_a = (np.asarray(ra) != ab.DNA.gap_code).sum(1)
+        assert (np.asarray(fwd.start_i) - used_a > 0).any()
+    if kind == "random" and B > 2:
+        assert int(k[2]) == 0
+
+
+@pytest.mark.parametrize("api", ["batch", "pairs"])
+@pytest.mark.parametrize("local", [False, True])
+def test_pallas_backend_matches_jnp(api, local):
+    """``pallas_align_batch`` / ``pallas_align_pairs`` (forward and
+    traceback kernels) return the ``jnp`` backend's alignment exactly."""
+    from repro.align import backends
+
+    a, t, lens = _walk_case(9, 48, 56, seed=31 + local)
+    sub = ab.dna_matrix().astype(jnp.float32)
+    kw = dict(gap_open=3, gap_extend=1, local=local,
+              gap_code=ab.DNA.gap_code)
+    if api == "batch":
+        args = (a, lens[:, 0], t[0], lens[0, 1], sub)
+        got = backends.pallas_align_batch(*args, **kw)
+        ref = backends.jnp_align_batch(*args, **kw)
+    else:
+        args = (a, lens[:, 0], t, lens[:, 1], sub)
+        got = backends.pallas_align_pairs(*args, **kw)
+        ref = backends.jnp_align_pairs(*args, **kw)
+    for x, y in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

@@ -196,3 +196,32 @@ def test_pallas_cell_plans_count_group_slots(monkeypatch, n_pairs, length,
                   backend="pallas") - calls0 == calls
     assert useful == n_pairs * length * length
     assert useful + pad == slots * length * length
+
+
+def _walked(walk):
+    return _value("repro_align_traceback_rows_total", walk=walk)
+
+
+@pytest.mark.parametrize("backend,walk", [("jnp", "scan"),
+                                          ("pallas", "kernel")])
+def test_traceback_rows_count_by_walk(monkeypatch, backend, walk):
+    """Rows walked back count as dispatched, under the walk that ran them:
+    the traceback kernel on ``pallas``, the jnp scan on ``jnp``. Five
+    queries split 2 + 2 + 1 by the direction budget walk 6 rows (the
+    last chunk's duplicate included); the pairs API walks its 3 rows."""
+    lens = np.array([40, 39, 37, 36, 34], np.int32)
+    rng = np.random.default_rng(3)
+    Q = rng.integers(0, 4, (5, 40)).astype(np.int8)
+    b = np.full(40, 5, np.int8)
+    b[:38] = rng.integers(0, 4, 38)
+    monkeypatch.setattr(engine_mod, "DIRS_BUDGET_BYTES", 2 * 40 * 41)
+    eng = AlignEngine(SUB, gap_open=3, gap_extend=1, gap_code=5,
+                      backend=backend)
+    other = {"scan": "kernel", "kernel": "scan"}[walk]
+    before = _walked(walk), _walked(other)
+    eng.align_to_center(Q, lens, b, jnp.int32(38))
+    assert _walked(walk) - before[0] == 6
+    eng.align_pairs(Q[:3], lens[:3], np.tile(b, (3, 1)),
+                    np.full(3, 38, np.int32))
+    assert _walked(walk) - before[0] == 9
+    assert _walked(other) == before[1]

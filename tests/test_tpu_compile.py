@@ -68,6 +68,32 @@ def test_sw_forward_cells(one_chip, B, n):
         ((B, 2), jnp.int32), (SUB, jnp.float32))
 
 
+@pytest.mark.parametrize("B,n,block", [(7, 16569, 32), (953, 1500, 128),
+                                       (5, 50, 8)])
+def test_sw_traceback_cells(one_chip, B, n, block):
+    """SW traceback at the benchmark cells' calls, on the forward kernel's
+    directions: mtdna-msa's 7 pairs (7 x 16,576 x 16,640 int8, 32-row
+    blocks) and rrna16s-nj's 953 pairs (953 x 1,536 x 1,536), and a
+    short query held whole (56 rows, the forward kernel's one 8-row
+    padded block): the kernel lowers, its direction windows fit VMEM,
+    and it is named apart from the forward kernel."""
+    from repro.core.pairwise import ForwardResult
+    from repro.kernels import round_up
+    from repro.kernels.sw.ops import sw_traceback_pallas
+
+    def walk(a, b, dirs, si, sj, ss):
+        fwd = ForwardResult(dirs, jnp.zeros(si.shape), si, sj, ss)
+        return sw_traceback_pallas(a, b, fwd, gap_code=5, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, n), jnp.int8), ((B, n), jnp.int8),
+        ((B, round_up(n, block), round_up(n + 1, 128)), jnp.int8),
+        ((B,), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))]
+    text = jax.jit(walk).lower(*args).compile().as_text()
+    assert "sw_traceback_pallas" in text and "tpu_custom_call" in text
+    assert "gotoh_forward_pallas" not in text
+
+
 @pytest.mark.parametrize("fused", [False, True])
 def test_banded_mtdna(one_chip, fused):
     """Banded forward and fused pairs at mtDNA length padded to 128
